@@ -260,6 +260,7 @@ def main(argv=None) -> dict:
         for d in lead[:-1]:
             batch *= d
         r.select_matmul_config(m, k, n, batch)
+        r.kernel_path("matmul")
         return jnp.dot(lhs, rhs, preferred_element_type=jnp.float32).astype(lhs.dtype)
 
     with rt.activate():

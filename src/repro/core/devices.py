@@ -78,20 +78,17 @@ def detect_device(env: dict | None = None) -> str:
 
     ``REPRO_DEVICE`` wins when set (itself canonicalized, so both
     ``REPRO_DEVICE=tpu_v4`` and ``REPRO_DEVICE="TPU v4"`` work).  Otherwise
-    the first jax device's kind/platform is normalized; a host where jax is
-    unavailable reports ``host_cpu``.
+    the first jax device's kind/platform is normalized.  A backend that fails
+    to initialise raises: it is never read as a CPU host.
     """
     e = env if env is not None else os.environ
     override = e.get(DEVICE_ENV_VAR)
     if override:
         return canonical_device_name(override)
-    try:
-        import jax
+    import jax
 
-        dev = jax.devices()[0]
-        return canonical_device_name(getattr(dev, "device_kind", ""), dev.platform)
-    except Exception:  # pragma: no cover - jax-less host
-        return "host_cpu"
+    dev = jax.devices()[0]
+    return canonical_device_name(getattr(dev, "device_kind", ""), dev.platform)
 
 
 def _family(name: str) -> str:

@@ -79,6 +79,10 @@ class KernelFamily:
     # family without one tunes full-harvest only.  For the analytic-model
     # families this is the untextured roofline (``texture=False``).
     model_matrix: Callable[[list[tuple], Sequence, str | None], np.ndarray] | None = None
+    # The TPU compiler's refusal of this family's Pallas kernel, or None when
+    # it lowers.  A refused family runs its reference on a TPU
+    # (``KernelRuntime.kernel_path``); the interpreter still runs the kernel.
+    tpu_refusal: str | None = None
 
     def make_tree(self, seed: int = 0):
         """A fresh (unfit) runtime classifier for this family."""
@@ -289,6 +293,8 @@ WKV = register_family(
         reference="repro.kernels.ref.wkv_ref",
         default_n_kernels=3,
         model_matrix=_wkv_model,
+        tpu_refusal="NotImplementedError: Unimplemented primitive in Pallas TPU "
+                    "lowering for KernelType.TC: cumsum",
     )
 )
 
@@ -307,6 +313,9 @@ SSM_SCAN = register_family(
         reference="repro.kernels.ref.ssm_scan_ref",
         default_n_kernels=4,
         model_matrix=_ssm_model,
+        tpu_refusal="NotImplementedError: Unimplemented primitive in Pallas TPU "
+                    "lowering for KernelType.TC: dynamic_slice (and block_d=64 "
+                    "blocks are not 128-lane aligned)",
     )
 )
 

@@ -12,7 +12,7 @@ everything that used to be global now lives on a :class:`KernelRuntime`:
   * the per-device policy registry + activation/epoch state (hot-swap unit);
   * per-thread shape-memoization caches and their counters;
   * the selection log (telemetry source of the continuous tuning loop);
-  * the Pallas dispatch flags.
+  * the Pallas dispatch path (taken from the platform; tests may override).
 
 Scoping: ``with rt.activate(): ...`` makes ``rt`` the innermost active
 runtime for the *current thread*; ``repro.kernels.ops`` dispatch
@@ -128,7 +128,7 @@ class KernelRuntime:
         self._device_policies: dict[str, object] = {}
         self._active_device: str | None = None
         self._requested_device: str | None = None
-        self.use_pallas: bool = False  # CPU host default: XLA dot
+        self._use_pallas: bool | None = None  # None: ask the platform on first use
         self.interpret: bool = False
         self._log_enabled: bool = False
         self._selection_log: deque[tuple] = deque(maxlen=DEFAULT_LOG_CAP)
@@ -345,11 +345,42 @@ class KernelRuntime:
             self.activate_device(target)
         return target
 
-    # -- pallas dispatch flags -------------------------------------------------
+    # -- pallas dispatch ---------------------------------------------------------
+    @property
+    def use_pallas(self) -> bool:
+        """Whether ops dispatch to the Pallas kernels.
+
+        Taken from the platform: compiled Pallas on a TPU backend, the XLA
+        reference everywhere else.  :meth:`set_pallas_enabled` overrides it
+        (kernel tests run the interpreter on CPU; a reference runtime on a
+        TPU turns Pallas off).
+        """
+        if self._use_pallas is None:
+            import jax
+
+            self._use_pallas = jax.default_backend() == "tpu"
+        return self._use_pallas
+
     def set_pallas_enabled(self, enabled: bool, *, interpret: bool = False) -> None:
-        """Route ops through the Pallas kernels (interpret=True on CPU)."""
-        self.use_pallas = enabled
+        """Override the platform's kernel path (``interpret=True`` on CPU)."""
+        self._use_pallas = enabled
         self.interpret = interpret
+
+    def kernel_path(self, family: str) -> str:
+        """``"pallas"`` if ``family`` dispatches to its Pallas kernel here,
+        else ``"xla"`` (its reference).
+
+        A family whose kernel the TPU compiler refuses
+        (``KernelFamily.tpu_refusal``) runs its reference when compiled;
+        the interpreter still runs its kernel.
+        """
+        if not self.use_pallas:
+            return "xla"
+        if self.interpret:
+            return "pallas"
+        from .families import get_family
+
+        return "xla" if get_family(family).tpu_refusal else "pallas"
 
     # -- failure containment (DESIGN.md §11) -----------------------------------
     def set_fault_plan(self, plan) -> None:

@@ -33,8 +33,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# v5e-flavoured VMEM budget used for config validity (conservative usable
-# fraction; the perf model uses the same constant).
+# v5e-flavoured VMEM budget (of 128 MiB physical) used for config validity;
+# the kernel asks the compiler for exactly this scoped limit, and the perf
+# model uses the same constant.
 VMEM_BYTES = 48 * 1024 * 1024
 _DOUBLE_BUFFER = 2  # Pallas pipelines input tiles with double buffering.
 
@@ -180,6 +181,9 @@ def matmul_pallas(
         if interpret
         else pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
+            # The budget is_valid() admits configs against; the compiler's
+            # default scoped limit is smaller and refuses the largest tiles.
+            vmem_limit_bytes=VMEM_BYTES,
         ),
     )(lhs, rhs)
     if (orig_m, orig_n) != (m, n):

@@ -14,10 +14,10 @@ default runtime.  The module-level mutators below
 runtime — byte-identical selections, kept for migration; see README's
 old→new map.  New code should hold a ``KernelRuntime`` and call its methods.
 
-A policy is produced by ``repro.core.tuner`` from benchmark data.  With no
-policy installed (or on hosts without a TPU), the op falls back to XLA's
-``jnp.dot`` — numerically identical to the Pallas path (same f32
-accumulation), which the kernel tests assert.
+A policy is produced by ``repro.core.tuner`` from benchmark data.  The kernel
+path comes from the platform (``KernelRuntime.kernel_path``): compiled Pallas
+on a TPU, XLA's ``jnp.dot`` / the jnp references elsewhere — numerically
+identical (same f32 accumulation), which the kernel tests assert.
 """
 from __future__ import annotations
 
@@ -358,7 +358,7 @@ def matmul(lhs: jax.Array, rhs: jax.Array, *, out_dtype=None, config: MatmulConf
     run_ref = lambda: jnp.dot(lhs, rhs, preferred_element_type=jnp.float32).astype(
         out_dtype or lhs.dtype
     )
-    if not rt.use_pallas:
+    if rt.kernel_path("matmul") == "xla":
         return _guarded_call(rt, "matmul", config, run_ref, run_ref)
     lhs2 = lhs.reshape(m * batch, k)
     run_tuned = lambda: matmul_pallas(
@@ -395,7 +395,7 @@ def attention(
         return fn(q, k, v)
 
     ref_fn = lambda q_, k_, v_: flash_attention_ref(q_, k_, v_, causal=causal, scale=scale)
-    if not rt.use_pallas:
+    if rt.kernel_path("attention") == "xla":
         run_ref = lambda: _apply(ref_fn)
         return _guarded_call(rt, "attention", config, run_ref, run_ref)
     cfg = config or DEFAULT_ATTN_CONFIG
@@ -414,7 +414,8 @@ def wkv(r, k, v, logw, u, state=None, *, config: WkvConfig | None = None):
     """Chunked WKV: r/k/v/logw (B, S, H, hd); u (H, hd); state (B, H, hd, hd).
 
     Returns (o (B, S, H, hd) f32, final_state).  Dispatches to the Pallas
-    kernel when enabled; otherwise the jnp reference (identical math).
+    kernel where it runs (``KernelRuntime.kernel_path``); otherwise the jnp
+    reference (identical math).
     """
     b, s, h, hd = r.shape
     rt = current_runtime()
@@ -423,7 +424,7 @@ def wkv(r, k, v, logw, u, state=None, *, config: WkvConfig | None = None):
     from .ref import wkv_ref
 
     run_ref = lambda: wkv_ref(r, k, v, logw, u, state)
-    if not rt.use_pallas:
+    if rt.kernel_path("wkv") == "xla":
         return _guarded_call(rt, "wkv", config, run_ref, run_ref)
     if state is None:
         import jax.numpy as _jnp
@@ -458,7 +459,7 @@ def ssm_scan(dtx, dta, b, v_c, state=None, *, config: SsmConfig | None = None):
     from .ref import ssm_scan_ref
 
     run_ref = lambda: ssm_scan_ref(dtx, dta, b, v_c, state)
-    if not rt.use_pallas:
+    if rt.kernel_path("ssm_scan") == "xla":
         return _guarded_call(rt, "ssm_scan", config, run_ref, run_ref)
     cfg = config or DEFAULT_SSM_CONFIG
     if state is None:

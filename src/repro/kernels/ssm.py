@@ -76,7 +76,7 @@ def _ssm_kernel(dtx_ref, dta_ref, b_ref, c_ref, s0_ref, y_ref, sout_ref, h_ref, 
         bx = dtx[t][:, None] * bvec[t][None, :]  # (bd, N)
         h = abar * h + bx
         y_t = jnp.sum(h * cvec[t][None, :], axis=1)  # (bd,)
-        pl.store(y_ref, (pl.dslice(t, 1), slice(None)), y_t[None, :].astype(y_ref.dtype))
+        y_ref[pl.ds(t, 1), :] = y_t[None, :].astype(y_ref.dtype)
         return h
 
     h = jax.lax.fori_loop(0, chunk, step, h_ref[...])

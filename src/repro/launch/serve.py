@@ -16,6 +16,7 @@ import numpy as np
 from repro.configs import registry
 from repro.core.retune import DEFAULT_DRIFT_THRESHOLD, DEFAULT_MIN_EVENTS
 from repro.core.runtime import KernelRuntime
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.model import build_model
 from repro.serve.engine import ServingEngine
 
@@ -23,7 +24,8 @@ from repro.serve.engine import ServingEngine
 def main(argv=None) -> None:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", required=True, choices=sorted(registry.ARCHS))
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action="store_true",
+                    help="tiny same-family config (CPU); default: the published widths")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--max-new-tokens", type=int, default=16)
     ap.add_argument("--max-batch", type=int, default=4)
@@ -58,8 +60,11 @@ def main(argv=None) -> None:
     ap.add_argument("--chaos-seed", type=int, default=0,
                     help="seed for the fault plan's probabilistic specs")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
-    cfg = registry.get(args.arch).reduced()
+    cfg = registry.get(args.arch)
+    if args.reduced:
+        cfg = cfg.reduced()
     # The launcher owns an explicit runtime handle: every policy, cache, and
     # telemetry mutation below is scoped to it (nothing process-global).
     rt = KernelRuntime(name=f"serve[{args.arch}]")
@@ -77,14 +82,14 @@ def main(argv=None) -> None:
 
         rt.install(Deployment.load(args.deployment))
 
-    model = build_model(cfg, dtype=jnp.float32, param_dtype=jnp.float32)
-    params = model.init(jax.random.PRNGKey(0))
+    model = build_model(cfg)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0))
 
     extra = {}
     if cfg.family == "vlm":
-        extra["image_embs"] = jnp.zeros((1, cfg.n_image_tokens, cfg.d_model), jnp.float32)
+        extra["image_embs"] = jnp.zeros((1, cfg.n_image_tokens, cfg.d_model), model.dtype)
     if cfg.family == "audio":
-        extra["frames"] = jnp.zeros((1, 32, cfg.d_model), jnp.float32)
+        extra["frames"] = jnp.zeros((1, 32, cfg.d_model), model.dtype)
 
     engine = ServingEngine(
         model, params, max_batch=args.max_batch, cache_len=args.cache_len,

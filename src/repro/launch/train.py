@@ -14,6 +14,7 @@ import jax.numpy as jnp
 from repro.configs import registry
 from repro.core.runtime import default_runtime
 from repro.data.pipeline import DataConfig
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.model import build_model
 from repro.optim import adamw
 from repro.train.trainer import Trainer, TrainerConfig
@@ -34,6 +35,7 @@ def main(argv=None) -> None:
     ap.add_argument("--fleet", action="store_true",
                     help="multi-host: init jax.distributed from the scheduler env")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     topo = None
     if args.fleet:

@@ -35,6 +35,7 @@ from repro.configs import registry
 from repro.core.cluster import CLUSTER_METHODS
 from repro.core.normalize import NORMALIZATIONS
 from repro.core.tuner import save_fleet, save_result, tune, tune_fleet, tune_for_archs
+from repro.launch.compile_cache import use_compile_cache
 
 
 def _measure_budget(text: str):
@@ -87,6 +88,7 @@ def main(argv=None) -> None:
                          "device from its donor's recorded lineage model_error "
                          "(donor-less tunes measure in full)")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     if not args.out and not args.bundle:
         ap.error("one of --out / --bundle is required")

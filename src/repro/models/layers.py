@@ -1,11 +1,10 @@
 """Shared model building blocks (pure JAX, functional params-in/out).
 
 All GEMMs route through ``repro.kernels.ops.matmul`` (the ML-guided kernel
-dispatcher).  Attention uses a memory-bounded chunked online-softmax
-implementation (flash-attention algorithm at the jnp level) so that 32k-token
-prefill fits per-device HBM without relying on XLA fusion heuristics; on TPU
-hosts the Pallas kernel path in ``repro.kernels`` takes over via
-``KernelRuntime.set_pallas_enabled``.
+dispatcher; compiled Pallas on a TPU, ``jnp.dot`` elsewhere).  Attention uses
+a memory-bounded chunked online-softmax implementation (flash-attention
+algorithm at the jnp level) so that 32k-token prefill fits per-device HBM
+without relying on XLA fusion heuristics.
 """
 from __future__ import annotations
 

@@ -251,6 +251,7 @@ class ServingEngine:
         clock=None,
         on_prefill=None,
         on_decode=None,
+        on_first_token=None,
         retune_interval: int | None = None,
         drift_threshold: float = DEFAULT_DRIFT_THRESHOLD,
         retune_min_events: int = DEFAULT_MIN_EVENTS,
@@ -353,6 +354,9 @@ class ServingEngine:
         self._clock = clock if clock is not None else time.perf_counter
         self.on_prefill = on_prefill
         self.on_decode = on_decode
+        # on_first_token(request, logits): the (1, 1, vocab) logits the first
+        # output token was taken from (what a correctness check compares).
+        self.on_first_token = on_first_token
 
         # -- continuous tuning loop (DESIGN.md §8) ---------------------------
         self.retune_interval = retune_interval
@@ -466,6 +470,8 @@ class ServingEngine:
         self.pool.note_tokens(slot, min(len(tail), plen))
         if self.on_prefill is not None:
             self.on_prefill(plen)
+        if self.on_first_token is not None:
+            self.on_first_token(req, logits)
         first = int(jnp.argmax(logits[0, -1]))
         req.output.append(first)
         req.state = "active"
@@ -601,6 +607,8 @@ class ServingEngine:
             # The block holding the decode frontier is never indexed (COW
             # rule), so shared content is immutable by construction.
             self.pool.register_prefix(lane, seq[:-1])
+        if self.on_first_token is not None:
+            self.on_first_token(req, req._first_logits)
         first = int(jnp.argmax(req._first_logits[0, -1]))
         req._first_logits = None  # free the device buffer
         req.output.append(first)

@@ -77,6 +77,19 @@ def test_detect_device_env_override(monkeypatch):
     assert detect_device() == "host_cpu"
 
 
+def test_detect_device_raises_when_backend_fails(monkeypatch):
+    """A backend that fails to initialise is an error, never a CPU host."""
+    import jax
+
+    def broken():
+        raise RuntimeError("Unable to initialize backend 'tpu'")
+
+    monkeypatch.delenv(DEVICE_ENV_VAR, raising=False)
+    monkeypatch.setattr(jax, "devices", broken)
+    with pytest.raises(RuntimeError, match="initialize backend"):
+        detect_device()
+
+
 def test_resolve_device_order():
     avail = ["tpu_v5e", "tpu_v4", "host_cpu"]
     assert resolve_device("tpu_v4", avail) == "tpu_v4"  # exact

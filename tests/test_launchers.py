@@ -14,11 +14,30 @@ from repro.kernels import ops
 @pytest.fixture(autouse=True)
 def _fresh_runtime():
     # Real test isolation: each test gets a brand-new default runtime instead
-    # of the old clear_*-everything teardown choreography.
+    # of the old clear_*-everything teardown choreography.  The mains point
+    # JAX's compile cache at the checkout; later tests keep the setting they
+    # started with.
     rt = reset_default_runtime()
     rt.set_selection_logging(True)
+    cache_dir = jax.config.jax_compilation_cache_dir
     yield rt
+    jax.config.update("jax_compilation_cache_dir", cache_dir)
     reset_default_runtime()
+
+
+def test_compile_cache_dir(monkeypatch):
+    from pathlib import Path
+
+    from repro.launch.compile_cache import ENV_VAR, use_compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv(ENV_VAR, "/elsewhere/jax")
+    assert use_compile_cache() == "/elsewhere/jax"
+    assert jax.config.jax_compilation_cache_dir == before  # JAX reads the env itself
+    monkeypatch.delenv(ENV_VAR)
+    checkout = Path(__file__).resolve().parents[1]
+    assert use_compile_cache() == str(checkout / ".jax_cache")
+    assert jax.config.jax_compilation_cache_dir == str(checkout / ".jax_cache")
 
 
 def test_tune_cli_v5e(tmp_path):
@@ -63,8 +82,8 @@ def test_train_cli_with_deployment(tmp_path):
 def test_serve_cli(tmp_path, capsys):
     from repro.launch.serve import main as serve_main
 
-    serve_main(["--arch", "granite-8b", "--requests", "3", "--max-new-tokens", "4",
-                "--max-batch", "2", "--cache-len", "64"])
+    serve_main(["--arch", "granite-8b", "--reduced", "--requests", "3",
+                "--max-new-tokens", "4", "--max-batch", "2", "--cache-len", "64"])
     out = capsys.readouterr().out
     assert "served 3 requests" in out
 
@@ -82,8 +101,8 @@ def test_tune_cli_bundle_then_serve_cli(tmp_path, capsys, monkeypatch):
     assert bundle.devices == ["tpu_v4", "tpu_v5e"]
     capsys.readouterr()
 
-    serve_main(["--arch", "granite-8b", "--requests", "2", "--max-new-tokens", "4",
-                "--max-batch", "2", "--cache-len", "64",
+    serve_main(["--arch", "granite-8b", "--reduced", "--requests", "2",
+                "--max-new-tokens", "4", "--max-batch", "2", "--cache-len", "64",
                 "--bundle", str(out), "--serve-device", "tpu_v4"])
     printed = capsys.readouterr().out
     assert "serving with the 'tpu_v4' deployment" in printed

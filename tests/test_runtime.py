@@ -113,6 +113,28 @@ def test_runtime_state_is_isolated():
     assert rt1.policy_epoch() == 1 and rt2.policy_epoch() == 2
 
 
+def test_kernel_path_follows_the_platform(monkeypatch):
+    """CPU: the XLA reference, no switch set.  A TPU backend: compiled Pallas,
+    except families whose kernel the TPU compiler refuses.  Only an explicit
+    override (tests) runs the interpreter."""
+    import jax
+
+    families = ("matmul", "attention", "wkv", "ssm_scan")
+    rt = KernelRuntime()
+    assert not rt.use_pallas and not rt.interpret
+    assert {rt.kernel_path(f) for f in families} == {"xla"}
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    tpu = KernelRuntime()
+    assert tpu.use_pallas and not tpu.interpret
+    assert [tpu.kernel_path(f) for f in families] == ["pallas", "pallas", "xla", "xla"]
+    tpu.set_pallas_enabled(False)  # a reference runtime on the chip
+    assert {tpu.kernel_path(f) for f in families} == {"xla"}
+
+    rt.set_pallas_enabled(True, interpret=True)
+    assert {rt.kernel_path(f) for f in families} == {"pallas"}
+
+
 def test_shape_cache_cap_reaches_other_threads():
     """rt.set_shape_cache_cap is runtime-scoped: fresh threads adopt it."""
     rt = KernelRuntime()

@@ -320,6 +320,19 @@ def test_prefill_budget_spreads_chunks_over_steps():
     assert t.done and sum(widths) == 32
 
 
+def test_on_first_token_sees_the_logits_of_the_first_token():
+    """Both admission regimes hand the hook the logits the first output
+    token is taken from (what chip_smoke compares with its reference)."""
+    for eng in (_engine()[0], _chunk_engine(prefill_chunk_tokens=8)):
+        seen = {}
+        eng.on_first_token = lambda req, logits: seen.__setitem__(req.uid, logits)
+        t = eng.submit([3, 4, 5], max_new_tokens=2, uid=7)
+        eng.drain()
+        assert list(seen) == [7]
+        assert seen[7].shape == (1, 1, ToyModel.vocab)
+        assert int(jnp.argmax(seen[7][0, -1])) == t.request.output[0] == 6
+
+
 def test_prefix_sharing_aliases_blocks_and_counts_hits():
     eng = _chunk_engine(prefill_chunk_tokens=16, cache_len=64)
     sys_prompt = list(range(1, 17))  # two full 8-token blocks once admitted
