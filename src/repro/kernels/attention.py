@@ -142,6 +142,7 @@ def flash_attention_pallas(
             pltpu.VMEM((bq, d), jnp.float32),
         ],
         interpret=interpret,
+        name=config.name(),
         compiler_params=None
         if interpret
         else pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary")),

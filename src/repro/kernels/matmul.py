@@ -177,6 +177,7 @@ def matmul_pallas(
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
+        name=config.name(),
         compiler_params=None
         if interpret
         else pltpu.CompilerParams(

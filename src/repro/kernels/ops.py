@@ -22,6 +22,7 @@ identical (same f32 accumulation), which the kernel tests assert.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import warnings
 from typing import Protocol
 
@@ -332,9 +333,25 @@ def _guarded_call(rt, family: str, config, run_tuned, run_ref):
         return run_ref()
 
 
+def _scoped(family: str):
+    """Trace a dispatch entry under ``ops.<family>``: the ops it emits carry
+    that scope in their metadata, so a device trace tells the families apart."""
+
+    def wrap(fn):
+        @functools.wraps(fn)
+        def scoped(*args, **kwargs):
+            with jax.named_scope(f"ops.{family}"):
+                return fn(*args, **kwargs)
+
+        return scoped
+
+    return wrap
+
+
 # ---------------------------------------------------------------------------
 # matmul
 # ---------------------------------------------------------------------------
+@_scoped("matmul")
 def matmul(lhs: jax.Array, rhs: jax.Array, *, out_dtype=None, config: MatmulConfig | None = None) -> jax.Array:
     """``lhs @ rhs`` with ML-guided kernel selection.
 
@@ -370,6 +387,7 @@ def matmul(lhs: jax.Array, rhs: jax.Array, *, out_dtype=None, config: MatmulConf
 # ---------------------------------------------------------------------------
 # attention
 # ---------------------------------------------------------------------------
+@_scoped("attention")
 def attention(
     q: jax.Array,
     k: jax.Array,
@@ -410,6 +428,7 @@ def attention(
 # ---------------------------------------------------------------------------
 # wkv (RWKV6 recurrence)
 # ---------------------------------------------------------------------------
+@_scoped("wkv")
 def wkv(r, k, v, logw, u, state=None, *, config: WkvConfig | None = None):
     """Chunked WKV: r/k/v/logw (B, S, H, hd); u (H, hd); state (B, H, hd, hd).
 
@@ -446,6 +465,7 @@ def wkv(r, k, v, logw, u, state=None, *, config: WkvConfig | None = None):
 # ---------------------------------------------------------------------------
 # selective-SSM scan (Mamba / Hymba recurrence)
 # ---------------------------------------------------------------------------
+@_scoped("ssm_scan")
 def ssm_scan(dtx, dta, b, v_c, state=None, *, config: SsmConfig | None = None):
     """Fused selective-SSM scan: dtx (B,S,d); dta (B,S,d,N); b/v_c (B,S,N).
 

@@ -144,6 +144,7 @@ def ssm_scan_pallas(
         ],
         scratch_shapes=[pltpu.VMEM((bd, n), jnp.float32)],
         interpret=interpret,
+        name=config.name(),
         compiler_params=None
         if interpret
         else pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary")),

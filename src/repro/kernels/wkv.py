@@ -160,6 +160,7 @@ def wkv_pallas(
         ],
         scratch_shapes=[pltpu.VMEM((hd, hd), jnp.float32)],
         interpret=interpret,
+        name=config.name(),
         compiler_params=None
         if interpret
         else pltpu.CompilerParams(dimension_semantics=("arbitrary",)),

@@ -374,11 +374,12 @@ def attention_layer(
                 k_cache = k_cache.at[bidx, cache_positions].set(k[:, 0])
                 v_cache = v_cache.at[bidx, cache_positions].set(v[:, 0])
             valid = cache_valid if cache_valid is not None else cache_positions + 1
-            out = decode_attention_jnp(
-                q, k_cache, v_cache, valid,
-                k_scale=ks_cache if quant else None,
-                v_scale=vs_cache if quant else None,
-            )
+            with jax.named_scope("ops.decode_attention"):
+                out = decode_attention_jnp(
+                    q, k_cache, v_cache, valid,
+                    k_scale=ks_cache if quant else None,
+                    v_scale=vs_cache if quant else None,
+                )
             new_cache = (k_cache, v_cache, ks_cache, vs_cache) if quant else (k_cache, v_cache)
         elif chunk_start is not None:  # chunk append: write [start, start+s)
             if quant:

@@ -64,6 +64,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import trace
 from repro.core.retune import DEFAULT_DRIFT_THRESHOLD, DEFAULT_MIN_EVENTS
 
 from .kvpool import KVPool
@@ -410,8 +411,12 @@ class ServingEngine:
 
     def _prefill_fn(self, plen: int):
         if plen not in self._prefill_cache:
-            fn = lambda params, batch: self.model.prefill(params, batch, self.cache_len)
-            self._prefill_cache[plen] = jax.jit(fn)
+            prefill, cache_len = self.model.prefill, self.cache_len
+
+            def program(params, batch):
+                return prefill(params, batch, cache_len)
+
+            self._prefill_cache[plen] = jax.jit(_named(program, f"serve_prefill_{plen}"))
         return self._prefill_cache[plen]
 
     def _seq_tokens(self, req: Request) -> np.ndarray:
@@ -439,44 +444,44 @@ class ServingEngine:
             self._admit_streaming(req, slot)
             return False
         plen = _bucket(len(self._seq_tokens(req)), self._ladder)
-        tail = self._seq_tokens(req)
-        if len(tail) > plen:
-            # Sliding-window truncation: a prompt longer than the largest
-            # prefill bucket keeps its most recent plen tokens (causal decode
-            # conditions on the suffix) instead of raising on the left-pad.
-            req.truncated_tokens = len(tail) - plen
-            tail = tail[-plen:]
-        prompt = np.zeros(plen, dtype=np.int32)
-        if len(tail):
-            prompt[-len(tail) :] = tail  # left-pad (causal end-aligned)
-        batch = {"tokens": jnp.asarray(prompt[None, :])}
-        for k, v in self.extra_inputs.items():
-            batch[k] = _batch_extra(k, v)
-        with self.runtime.activate():  # trace-time selections hit OUR runtime
-            logits, cache1 = self._run_program(
-                "engine.prefill",
-                lambda: self._prefill_fn(plen)(self.params, batch),
-                retrace=lambda: self._prefill_cache.pop(plen, None),
-                request=req,
-            )
-        # Back the lane with blocks and scatter the single-sequence prefill
-        # cache into them (the lane's previous tenant, if any, is reclaimed).
-        self.pool.release(slot)
-        if not self.pool.ensure(slot, plen):
-            raise RuntimeError(
-                f"admitted request {req.uid} with no blocks for plen={plen}"
-            )
-        self.pool.admit(slot, cache1)
-        self.pool.note_tokens(slot, min(len(tail), plen))
-        if self.on_prefill is not None:
-            self.on_prefill(plen)
-        if self.on_first_token is not None:
-            self.on_first_token(req, logits)
-        first = int(jnp.argmax(logits[0, -1]))
-        req.output.append(first)
-        req.state = "active"
-        self.slots[slot] = req
-        self.positions[slot] = plen
+        with trace.span("serve.prefill", request=req.uid, plen=plen):
+            tail = self._seq_tokens(req)
+            if len(tail) > plen:
+                # Sliding-window truncation: a prompt longer than the largest
+                # prefill bucket keeps its most recent plen tokens (causal decode
+                # conditions on the suffix) instead of raising on the left-pad.
+                req.truncated_tokens = len(tail) - plen
+                tail = tail[-plen:]
+            prompt = np.zeros(plen, dtype=np.int32)
+            if len(tail):
+                prompt[-len(tail) :] = tail  # left-pad (causal end-aligned)
+            batch = {"tokens": jnp.asarray(prompt[None, :])}
+            for k, v in self.extra_inputs.items():
+                batch[k] = _batch_extra(k, v)
+            with self.runtime.activate():  # trace-time selections hit OUR runtime
+                logits, cache1 = self._run_program(
+                    "engine.prefill", self._prefill_cache, plen,
+                    lambda: self._prefill_fn(plen)(self.params, batch),
+                    request=req,
+                )
+            # Back the lane with blocks and scatter the single-sequence prefill
+            # cache into them (the lane's previous tenant, if any, is reclaimed).
+            self.pool.release(slot)
+            if not self.pool.ensure(slot, plen):
+                raise RuntimeError(
+                    f"admitted request {req.uid} with no blocks for plen={plen}"
+                )
+            self.pool.admit(slot, cache1)
+            self.pool.note_tokens(slot, min(len(tail), plen))
+            if self.on_prefill is not None:
+                self.on_prefill(plen)
+            if self.on_first_token is not None:
+                self.on_first_token(req, logits)
+            first = int(jnp.argmax(logits[0, -1]))
+            req.output.append(first)
+            req.state = "active"
+            self.slots[slot] = req
+            self.positions[slot] = plen
         return True
 
     # -- streaming admission (chunked prefill + prefix sharing) ---------------
@@ -506,11 +511,12 @@ class ServingEngine:
             # skipping the trace-time kernel selection that must run under
             # THIS engine's runtime and policy.
             chunk = self.model.prefill_chunk
+
+            def program(params, cache, tokens, start, last):
+                return chunk(params, cache, tokens, start, last)
+
             self._chunk_cache[width] = jax.jit(
-                lambda params, cache, tokens, start, last: chunk(
-                    params, cache, tokens, start, last
-                ),
-                donate_argnums=(1,),
+                _named(program, f"serve_chunk_w{width}"), donate_argnums=(1,)
             )
         return self._chunk_cache[width]
 
@@ -566,55 +572,57 @@ class ServingEngine:
         chunk = np.zeros(width, dtype=np.int32)
         real = toks[s0 : s0 + width]
         chunk[: len(real)] = real
-        with self.runtime.activate():
-            logits, cache = self._run_program(
-                "engine.prefill",
-                lambda: self._chunk_fn(width)(
-                    self.params,
-                    self.pool.gather([lane]),  # re-gathered on retry: donation-safe
-                    jnp.asarray(chunk[None, :]),
-                    jnp.int32(s0),
-                    jnp.int32(len(real) - 1),
-                ),
-                retrace=lambda: self._chunk_cache.pop(width, None),
-                request=req,
-            )
-        self.pool.scatter([lane], cache)
-        if self.on_prefill is not None:
-            self.on_prefill(width)
-        req._chunk_pos = min(s0 + width, len(toks))
-        if req._chunk_pos >= len(toks):
-            # Final chunk: its last real row predicts the first output token.
-            req._first_logits = logits
-        if self._sharing:
-            # Index the blocks this chunk completed right away, so siblings
-            # admitted while a long prompt is still prefilling can alias the
-            # finished span instead of waiting for activation.  Only the
-            # body (all but the last token) is ever indexed — the block
-            # holding the decode frontier stays private (COW rule).
-            self.pool.register_prefix(
-                lane, toks[: min(req._chunk_pos, len(toks) - 1)]
-            )
+        with trace.span("serve.chunk", request=req.uid, lane=lane, start=int(s0),
+                        n_real=len(real), width=width, final=s0 + len(real) >= len(toks)):
+            with self.runtime.activate():
+                logits, cache = self._run_program(
+                    "engine.prefill", self._chunk_cache, width,
+                    lambda: self._chunk_fn(width)(
+                        self.params,
+                        self.pool.gather([lane]),  # re-gathered on retry: donation-safe
+                        jnp.asarray(chunk[None, :]),
+                        jnp.int32(s0),
+                        jnp.int32(len(real) - 1),
+                    ),
+                    request=req,
+                )
+            self.pool.scatter([lane], cache)
+            if self.on_prefill is not None:
+                self.on_prefill(width)
+            req._chunk_pos = min(s0 + width, len(toks))
+            if req._chunk_pos >= len(toks):
+                # Final chunk: its last real row predicts the first output token.
+                req._first_logits = logits
+            if self._sharing:
+                # Index the blocks this chunk completed right away, so siblings
+                # admitted while a long prompt is still prefilling can alias the
+                # finished span instead of waiting for activation.  Only the
+                # body (all but the last token) is ever indexed — the block
+                # holding the decode frontier stays private (COW rule).
+                self.pool.register_prefix(
+                    lane, toks[: min(req._chunk_pos, len(toks) - 1)]
+                )
 
     def _activate_lane(self, lane: int, req: Request) -> None:
         """Sequence fully cached: emit the first token from the final
         chunk's logits and join the batched decode.  No program runs here —
         activation costs nothing beyond the chunks themselves, matching the
         legacy prefill's first-token-from-last-position-logits economics."""
-        seq = req._chunk_tokens
-        if self._sharing:
-            # Index the lane's fully-covered body blocks for future reuse.
-            # The block holding the decode frontier is never indexed (COW
-            # rule), so shared content is immutable by construction.
-            self.pool.register_prefix(lane, seq[:-1])
-        if self.on_first_token is not None:
-            self.on_first_token(req, req._first_logits)
-        first = int(jnp.argmax(req._first_logits[0, -1]))
-        req._first_logits = None  # free the device buffer
-        req.output.append(first)
-        req.state = "active"
-        self.positions[lane] = len(seq)
-        self.pool.note_tokens(lane, len(seq))
+        with trace.span("serve.activate", request=req.uid):
+            seq = req._chunk_tokens
+            if self._sharing:
+                # Index the lane's fully-covered body blocks for future reuse.
+                # The block holding the decode frontier is never indexed (COW
+                # rule), so shared content is immutable by construction.
+                self.pool.register_prefix(lane, seq[:-1])
+            if self.on_first_token is not None:
+                self.on_first_token(req, req._first_logits)
+            first = int(jnp.argmax(req._first_logits[0, -1]))
+            req._first_logits = None  # free the device buffer
+            req.output.append(first)
+            req.state = "active"
+            self.positions[lane] = len(seq)
+            self.pool.note_tokens(lane, len(seq))
 
     def _advance_prefills(self) -> tuple[list[Request], list[Request]]:
         """Run chunk programs for ``"prefilling"`` lanes within this step's
@@ -728,9 +736,12 @@ class ServingEngine:
             # Fresh closure per jit — see _chunk_fn for why the bound method
             # must not be jitted directly.
             step = self.model.decode_step
+
+            def program(params, cache, tokens, pos):
+                return step(params, cache, tokens, pos)
+
             self._decode_cache[width] = jax.jit(
-                lambda params, cache, tokens, pos: step(params, cache, tokens, pos),
-                donate_argnums=(1,),
+                _named(program, f"serve_decode_w{width}"), donate_argnums=(1,)
             )
         return self._decode_cache[width]
 
@@ -745,59 +756,60 @@ class ServingEngine:
         if not active:
             return []
         width = self._width(len(active))
-        # Pad the batch to the bucket with idle lanes (their block tables are
-        # empty or retired, so their writes land in scratch / reclaimed rows
-        # — same as the seed engine decoding its idle slots; a retired lane's
-        # *registered* prefix blocks are safe because the stale write position
-        # is at/beyond the old decode frontier, outside every indexed block).
-        # When mid-prefill lanes leave too few idle lanes, they serve as
-        # padding too: the pad write lands at the last prompt position (at
-        # or past every finished chunk), which the lane's final chunk
-        # overwrites with the real last-token k/v before activation.
-        idle = [i for i, r in enumerate(self.slots) if r is None]
-        idle += [
-            i for i, r in enumerate(self.slots)
-            if r is not None and r.state == "prefilling"
-        ]
-        sel = active + idle[: width - len(active)]
-        tokens = np.zeros((width, 1), dtype=np.int32)
-        for row, lane in enumerate(active):
-            tokens[row, 0] = self.slots[lane].output[-1]
-        pos = self.positions[sel]
-        with self.runtime.activate():  # trace-time selections hit OUR runtime
-            logits, new_cache = self._run_program(
-                "engine.decode",
-                lambda: self._decode_fn(width)(
-                    self.params,
-                    self.pool.gather(sel),  # re-gathered on retry: donation-safe
-                    jnp.asarray(tokens),
-                    jnp.asarray(pos),
-                ),
-                retrace=lambda: self._decode_cache.pop(width, None),
-            )
-        self.pool.scatter(sel, new_cache)
-        if self.on_decode is not None:
-            self.on_decode(width)
-        nxt = np.asarray(jnp.argmax(logits[:, -1], axis=-1))
-        got = []
-        for row, lane in enumerate(active):
-            r = self.slots[lane]
-            self.positions[lane] += 1
-            tok = int(nxt[row])
-            r.output.append(tok)
-            got.append(r)
-            if (
-                len(r.output) >= r.max_new_tokens
-                or (r.eos_id is not None and tok == r.eos_id)
-                or self.positions[lane] >= self.cache_len - 1
-            ):
-                r.done = True
-                r.state = "done"
-                self.slots[lane] = None
-                # Lazy reclaim: blocks stay readable (pool.retire) until a
-                # later admission actually needs them.
-                self.pool.retire(lane)
-        self.steps += 1
+        with trace.span("serve.decode", width=width, rows=len(active)):
+            # Pad the batch to the bucket with idle lanes (their block tables are
+            # empty or retired, so their writes land in scratch / reclaimed rows
+            # — same as the seed engine decoding its idle slots; a retired lane's
+            # *registered* prefix blocks are safe because the stale write position
+            # is at/beyond the old decode frontier, outside every indexed block).
+            # When mid-prefill lanes leave too few idle lanes, they serve as
+            # padding too: the pad write lands at the last prompt position (at
+            # or past every finished chunk), which the lane's final chunk
+            # overwrites with the real last-token k/v before activation.
+            idle = [i for i, r in enumerate(self.slots) if r is None]
+            idle += [
+                i for i, r in enumerate(self.slots)
+                if r is not None and r.state == "prefilling"
+            ]
+            sel = active + idle[: width - len(active)]
+            tokens = np.zeros((width, 1), dtype=np.int32)
+            for row, lane in enumerate(active):
+                tokens[row, 0] = self.slots[lane].output[-1]
+            pos = self.positions[sel]
+            with self.runtime.activate():  # trace-time selections hit OUR runtime
+                logits, new_cache = self._run_program(
+                    "engine.decode", self._decode_cache, width,
+                    lambda: self._decode_fn(width)(
+                        self.params,
+                        self.pool.gather(sel),  # re-gathered on retry: donation-safe
+                        jnp.asarray(tokens),
+                        jnp.asarray(pos),
+                    ),
+                )
+            self.pool.scatter(sel, new_cache)
+            if self.on_decode is not None:
+                self.on_decode(width)
+            with trace.span("serve.sample", width=width):
+                nxt = np.asarray(jnp.argmax(logits[:, -1], axis=-1))
+            got = []
+            for row, lane in enumerate(active):
+                r = self.slots[lane]
+                self.positions[lane] += 1
+                tok = int(nxt[row])
+                r.output.append(tok)
+                got.append(r)
+                if (
+                    len(r.output) >= r.max_new_tokens
+                    or (r.eos_id is not None and tok == r.eos_id)
+                    or self.positions[lane] >= self.cache_len - 1
+                ):
+                    r.done = True
+                    r.state = "done"
+                    self.slots[lane] = None
+                    # Lazy reclaim: blocks stay readable (pool.retire) until a
+                    # later admission actually needs them.
+                    self.pool.retire(lane)
+            self.steps += 1
         return got
 
     # -- SLO pressure (objective-aware selection) -----------------------------
@@ -898,16 +910,18 @@ class ServingEngine:
         self._decode_cache.clear()
         self._chunk_cache.clear()
 
-    def _run_program(self, site: str, fn, *, retrace, request: Request | None = None):
+    def _run_program(self, site: str, programs: dict, key: int, fn, *,
+                     request: Request | None = None):
         """Run one compiled program with per-request retry-on-kernel-fault.
 
+        ``fn`` calls the program ``programs[key]``, building it on a miss.
         The engine-level safety net above the ops-layer guard: an injected
         fault at ``site`` (fired *before* execution, so donated buffers are
         never half-consumed) or a real failure escaping the compiled program
-        gets one retry after ``retrace()`` drops the compiled artifact —
-        the re-trace re-runs kernel selection, picking up any quarantine the
-        ops guard installed meanwhile.  A second failure propagates: zero
-        silent drops, but also no infinite retry loop.
+        gets one retry after the compiled artifact is dropped — the re-trace
+        re-runs kernel selection, picking up any quarantine the ops guard
+        installed meanwhile.  A second failure propagates: zero silent
+        drops, but also no infinite retry loop.
         """
         from repro.core.faults import GUARDED_EXCEPTIONS, incident
 
@@ -916,14 +930,14 @@ class ServingEngine:
         try:
             if plan is not None:
                 plan.raise_if(site)
-            return fn()
+            return _launch(site, programs, key, fn)
         except GUARDED_EXCEPTIONS as e:
             rt.record_incident(incident(
                 site, "engine", None, e, "retry", device=rt.active_device()))
             if request is not None:
                 request.retries += 1
-            retrace()
-            return fn()
+            programs.pop(key, None)
+            return _launch(site, programs, key, fn)
 
     def _update_health(self) -> str:
         """Advance the healthy/degraded state machine; record transitions.
@@ -1223,60 +1237,67 @@ class ServingEngine:
         Returns False when no progress was possible (nothing admitted and no
         active lane decoded) — callers looping on ``step()`` should stop.
         """
-        t0 = self._clock()
-        # A control-plane offer adopts on the step boundary: before any
-        # admission or decode of this round, so the whole step runs under one
-        # policy and no in-flight request straddles the swap mid-trace.
-        offer = self._take_offer()
-        if offer is not None:
-            self.adopt_deployment(offer[0], source=offer[1])
-        # SLO check runs BEFORE admission: it sees the same step-time history
-        # it would at the end of the previous step, but entering now means
-        # this step's admissions and traces already run under the cap and the
-        # latency objective (no full-width burst right as a target lands).
-        self._update_slo()
-        self.scheduler.begin_step()  # fresh prefill-token budget
-        emitted: list[Request] = []
-        preempted_once = False
-        while len(self.scheduler):
-            lane = self._free_lane()
-            if lane is None or self._admit_blocked():
-                break
-            req = self.scheduler.pop_next(self.steps, fits=self._fits)
-            if req is None:
-                if preempted_once:
-                    break
-                preempted_once = True
-                req = self._preempt_for_admission()
-                if req is None:
-                    break
-                lane = self._free_lane()
-            if self._admit(req, lane):
-                emitted.append(req)  # legacy prefill emitted the first token
-        # Budgeted chunk work for prefilling lanes; a lane whose final chunk
-        # landed this step emits its first token here and joins this step's
-        # batched decode below (streaming parity with legacy: a small prompt
-        # admitted this step still answers this step).
-        progressed, activated = self._advance_prefills()
-        emitted.extend(activated)
-        self._grow_active()
-        decoded = self._decode_active()
-        emitted.extend(decoded)
-        self._update_health()
-        self.maybe_rollback()
-        if (
-            self.retune_interval is not None
-            and self.steps - self._last_retune_check >= self.retune_interval
-        ):
-            self._last_retune_check = self.steps
-            self.maybe_retune()
-        dt_ms = (self._clock() - t0) * 1e3
-        self._step_ms.append(dt_ms)
-        for r in emitted:
-            r.token_ms.append(dt_ms)
-        # Chunk work without a token is still progress: streaming callers
-        # (Ticket.tokens) must keep stepping while a long prompt prefills.
-        return bool(emitted or progressed)
+        with trace.span("serve.step", step=self.steps):
+            t0 = self._clock()
+            # A control-plane offer adopts on the step boundary: before any
+            # admission or decode of this round, so the whole step runs under one
+            # policy and no in-flight request straddles the swap mid-trace.
+            offer = self._take_offer()
+            if offer is not None:
+                self.adopt_deployment(offer[0], source=offer[1])
+            # SLO check runs BEFORE admission: it sees the same step-time history
+            # it would at the end of the previous step, but entering now means
+            # this step's admissions and traces already run under the cap and the
+            # latency objective (no full-width burst right as a target lands).
+            self._update_slo()
+            self.scheduler.begin_step()  # fresh prefill-token budget
+            emitted: list[Request] = []
+            with trace.span("serve.schedule", queued=len(self.scheduler)) as sp:
+                admitted = 0
+                preempted_once = False
+                while len(self.scheduler):
+                    lane = self._free_lane()
+                    if lane is None or self._admit_blocked():
+                        break
+                    req = self.scheduler.pop_next(self.steps, fits=self._fits)
+                    if req is None:
+                        if preempted_once:
+                            break
+                        preempted_once = True
+                        req = self._preempt_for_admission()
+                        if req is None:
+                            break
+                        lane = self._free_lane()
+                    admitted += 1
+                    if self._admit(req, lane):
+                        emitted.append(req)  # legacy prefill emitted the first token
+                sp.set(admitted=admitted)
+            # Budgeted chunk work for prefilling lanes; a lane whose final chunk
+            # landed this step emits its first token here and joins this step's
+            # batched decode below (streaming parity with legacy: a small prompt
+            # admitted this step still answers this step).
+            progressed, activated = self._advance_prefills()
+            emitted.extend(activated)
+            with trace.span("serve.schedule", queued=len(self.scheduler)):
+                self._grow_active()
+            decoded = self._decode_active()
+            emitted.extend(decoded)
+            with trace.span("serve.watchdogs"):
+                self._update_health()
+                self.maybe_rollback()
+                if (
+                    self.retune_interval is not None
+                    and self.steps - self._last_retune_check >= self.retune_interval
+                ):
+                    self._last_retune_check = self.steps
+                    self.maybe_retune()
+            dt_ms = (self._clock() - t0) * 1e3
+            self._step_ms.append(dt_ms)
+            for r in emitted:
+                r.token_ms.append(dt_ms)
+            # Chunk work without a token is still progress: streaming callers
+            # (Ticket.tokens) must keep stepping while a long prompt prefills.
+            return bool(emitted or progressed)
 
     def status(self) -> EngineStatus:
         """Live snapshot over this serving epoch (since the last drain).
@@ -1376,6 +1397,21 @@ class ServingEngine:
         for r in requests:
             self.submit_request(r)
         return self.drain(max_steps=max_steps)
+
+
+def _named(fn, name: str):
+    """Name a step program: jit names its module ``jit_<name>``, which is how
+    a device trace tells the engine's programs from eager ops."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
+
+
+def _launch(site: str, programs: dict, key: int, fn):
+    # The first call of a program the engine has just built traces and
+    # compiles it: a span of its own, apart from the launches.
+    name = "serve.launch" if key in programs else "serve.compile"
+    with trace.span(name, site=site, width=key):
+        return fn()
 
 
 def _batch_extra(key: str, v) -> jax.Array:

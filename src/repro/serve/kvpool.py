@@ -63,6 +63,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import trace
+
 __all__ = ["KVPool", "LeafSpec"]
 
 _PROBE_BATCHES = (3, 5, 7, 11, 13)
@@ -339,14 +341,15 @@ class KVPool:
         chain hash wins; re-registering identical content is a no-op.
         Returns the number of shareable blocks.
         """
-        table = self._tables[lane]
-        n = 0
-        for h, blk in zip(self._chain_hashes(tokens), table):
-            if h not in self._prefix_index:
-                self._prefix_index[h] = blk
-                self._block_hash[blk] = h
-            n += 1
-        return n
+        with trace.span("kvpool.index", tokens=len(tokens)):
+            table = self._tables[lane]
+            n = 0
+            for h, blk in zip(self._chain_hashes(tokens), table):
+                if h not in self._prefix_index:
+                    self._prefix_index[h] = blk
+                    self._block_hash[blk] = h
+                n += 1
+            return n
 
     def admit_prefix(self, lane: int, tokens) -> int:
         """Release ``lane``'s previous tenant and seed it with the longest
@@ -358,13 +361,14 @@ class KVPool:
         would otherwise free it.  Returns the number of prefix tokens served
         from cache.
         """
-        matched = self.match_prefix(tokens)
-        for blk in matched:
-            self._rc[blk] += 1  # reserve against the release below
-        self.release(lane)
-        self._tables[lane] = list(matched)
-        self._lane_tokens[lane] = len(matched) * self.block_size
-        return len(matched) * self.block_size
+        with trace.span("kvpool.index", tokens=len(tokens)):
+            matched = self.match_prefix(tokens)
+            for blk in matched:
+                self._rc[blk] += 1  # reserve against the release below
+            self.release(lane)
+            self._tables[lane] = list(matched)
+            self._lane_tokens[lane] = len(matched) * self.block_size
+            return len(matched) * self.block_size
 
     def alias(self, lane: int, blocks) -> None:
         """Seed a fresh lane's table with shared ``blocks`` (incref each)."""
@@ -448,29 +452,30 @@ class KVPool:
         Only the lane's allocated blocks are written; content beyond them is
         zero in ``cache1`` by the masking invariant (see module docstring).
         """
-        leaves = self.treedef.flatten_up_to(cache1)
-        table = self._tables[lane]
-        ids = np.asarray(table, dtype=np.int32)
-        for i, (spec, leaf) in enumerate(zip(self.specs, leaves)):
-            if spec.kind == "replicated":
-                # Dense-engine parity: _scatter_slot kept the pool's value.
-                continue
-            arr = self._store[i]
-            if spec.kind == "lane":
-                idx = [slice(None)] * arr.ndim
-                idx[spec.batch_axis] = slice(lane, lane + 1)
-                self._store[i] = arr.at[tuple(idx)].set(leaf)
-                continue
-            # paged: (…,1,…,cache_len,…) -> (blocks_per_lane, block_size, rest)
-            canon = jnp.moveaxis(leaf, (spec.batch_axis, spec.length_axis), (0, 1))[0]
-            chunks = canon.reshape(
-                (self.blocks_per_lane, self.block_size) + canon.shape[1:]
-            )
-            pooled = jnp.moveaxis(arr, (spec.batch_axis, spec.length_axis), (0, 1))
-            pooled = pooled.at[ids].set(chunks[: len(table)])
-            self._store[i] = jnp.moveaxis(
-                pooled, (0, 1), (spec.batch_axis, spec.length_axis)
-            )
+        with trace.span("kvpool.admit", lanes=1):
+            leaves = self.treedef.flatten_up_to(cache1)
+            table = self._tables[lane]
+            ids = np.asarray(table, dtype=np.int32)
+            for i, (spec, leaf) in enumerate(zip(self.specs, leaves)):
+                if spec.kind == "replicated":
+                    # Dense-engine parity: _scatter_slot kept the pool's value.
+                    continue
+                arr = self._store[i]
+                if spec.kind == "lane":
+                    idx = [slice(None)] * arr.ndim
+                    idx[spec.batch_axis] = slice(lane, lane + 1)
+                    self._store[i] = arr.at[tuple(idx)].set(leaf)
+                    continue
+                # paged: (…,1,…,cache_len,…) -> (blocks_per_lane, block_size, rest)
+                canon = jnp.moveaxis(leaf, (spec.batch_axis, spec.length_axis), (0, 1))[0]
+                chunks = canon.reshape(
+                    (self.blocks_per_lane, self.block_size) + canon.shape[1:]
+                )
+                pooled = jnp.moveaxis(arr, (spec.batch_axis, spec.length_axis), (0, 1))
+                pooled = pooled.at[ids].set(chunks[: len(table)])
+                self._store[i] = jnp.moveaxis(
+                    pooled, (0, 1), (spec.batch_axis, spec.length_axis)
+                )
 
     def gather(self, lane_ids) -> object:
         """Materialise the dense decode view for ``lane_ids``.
@@ -480,27 +485,28 @@ class KVPool:
         through untouched.
         """
         lane_ids = list(lane_ids)
-        idx = jnp.asarray(self._padded_tables(lane_ids).reshape(-1))
-        rows = jnp.asarray(np.asarray(lane_ids, dtype=np.int32))
-        out = []
-        for spec, arr in zip(self.specs, self._store):
-            if spec.kind == "replicated":
-                out.append(arr)
-            elif spec.kind == "lane":
-                out.append(jnp.take(arr, rows, axis=spec.batch_axis))
-            else:
-                pooled = jnp.moveaxis(
-                    arr, (spec.batch_axis, spec.length_axis), (0, 1)
-                )
-                got = jnp.take(pooled, idx, axis=0)  # (W*bpl, block, rest)
-                got = got.reshape(
-                    (len(lane_ids), self.blocks_per_lane * self.block_size)
-                    + got.shape[2:]
-                )
-                out.append(
-                    jnp.moveaxis(got, (0, 1), (spec.batch_axis, spec.length_axis))
-                )
-        return jax.tree_util.tree_unflatten(self.treedef, out)
+        with trace.span("kvpool.gather", lanes=len(lane_ids)):
+            idx = jnp.asarray(self._padded_tables(lane_ids).reshape(-1))
+            rows = jnp.asarray(np.asarray(lane_ids, dtype=np.int32))
+            out = []
+            for spec, arr in zip(self.specs, self._store):
+                if spec.kind == "replicated":
+                    out.append(arr)
+                elif spec.kind == "lane":
+                    out.append(jnp.take(arr, rows, axis=spec.batch_axis))
+                else:
+                    pooled = jnp.moveaxis(
+                        arr, (spec.batch_axis, spec.length_axis), (0, 1)
+                    )
+                    got = jnp.take(pooled, idx, axis=0)  # (W*bpl, block, rest)
+                    got = got.reshape(
+                        (len(lane_ids), self.blocks_per_lane * self.block_size)
+                        + got.shape[2:]
+                    )
+                    out.append(
+                        jnp.moveaxis(got, (0, 1), (spec.batch_axis, spec.length_axis))
+                    )
+            return jax.tree_util.tree_unflatten(self.treedef, out)
 
     def scatter(self, lane_ids, cache) -> None:
         """Write an updated dense view back into the pool.
@@ -509,36 +515,37 @@ class KVPool:
         re-zeroed afterwards so later gathers still read zeros there.
         """
         lane_ids = list(lane_ids)
-        idx = jnp.asarray(self._padded_tables(lane_ids).reshape(-1))
-        rows = jnp.asarray(np.asarray(lane_ids, dtype=np.int32))
-        leaves = self.treedef.flatten_up_to(cache)
-        touched_scratch = False
-        for i, (spec, leaf) in enumerate(zip(self.specs, leaves)):
-            arr = self._store[i]
-            if spec.kind == "replicated":
-                # Dense-engine parity: the decode output's replicated leaves
-                # became the pool wholesale.
-                self._store[i] = leaf
-            elif spec.kind == "lane":
-                moved = jnp.moveaxis(arr, spec.batch_axis, 0)
-                new = jnp.moveaxis(leaf, spec.batch_axis, 0)
-                moved = moved.at[rows].set(new)
-                self._store[i] = jnp.moveaxis(moved, 0, spec.batch_axis)
-            else:
-                pooled = jnp.moveaxis(
-                    arr, (spec.batch_axis, spec.length_axis), (0, 1)
-                )
-                canon = jnp.moveaxis(
-                    leaf, (spec.batch_axis, spec.length_axis), (0, 1)
-                )
-                chunks = canon.reshape(
-                    (len(lane_ids) * self.blocks_per_lane, self.block_size)
-                    + canon.shape[2:]
-                )
-                pooled = pooled.at[idx].set(chunks)
-                self._store[i] = jnp.moveaxis(
-                    pooled, (0, 1), (spec.batch_axis, spec.length_axis)
-                )
-                touched_scratch = True
-        if touched_scratch:
-            self._zero_block(0)
+        with trace.span("kvpool.scatter", lanes=len(lane_ids)):
+            idx = jnp.asarray(self._padded_tables(lane_ids).reshape(-1))
+            rows = jnp.asarray(np.asarray(lane_ids, dtype=np.int32))
+            leaves = self.treedef.flatten_up_to(cache)
+            touched_scratch = False
+            for i, (spec, leaf) in enumerate(zip(self.specs, leaves)):
+                arr = self._store[i]
+                if spec.kind == "replicated":
+                    # Dense-engine parity: the decode output's replicated leaves
+                    # became the pool wholesale.
+                    self._store[i] = leaf
+                elif spec.kind == "lane":
+                    moved = jnp.moveaxis(arr, spec.batch_axis, 0)
+                    new = jnp.moveaxis(leaf, spec.batch_axis, 0)
+                    moved = moved.at[rows].set(new)
+                    self._store[i] = jnp.moveaxis(moved, 0, spec.batch_axis)
+                else:
+                    pooled = jnp.moveaxis(
+                        arr, (spec.batch_axis, spec.length_axis), (0, 1)
+                    )
+                    canon = jnp.moveaxis(
+                        leaf, (spec.batch_axis, spec.length_axis), (0, 1)
+                    )
+                    chunks = canon.reshape(
+                        (len(lane_ids) * self.blocks_per_lane, self.block_size)
+                        + canon.shape[2:]
+                    )
+                    pooled = pooled.at[idx].set(chunks)
+                    self._store[i] = jnp.moveaxis(
+                        pooled, (0, 1), (spec.batch_axis, spec.length_axis)
+                    )
+                    touched_scratch = True
+            if touched_scratch:
+                self._zero_block(0)
