@@ -5,6 +5,7 @@ The pool is exercised standalone against the serving toy-model cache layout
 its gather/scatter round-trip is pinned against the dense ``_scatter_slot``
 path it replaced.
 """
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -321,3 +322,194 @@ def test_replicated_leaf_passes_through_unpooled():
     back = pool.gather([0, 1])
     assert np.asarray(back["rope"]).min() == 3.0  # adopted wholesale
     assert np.asarray(back["k"])[0, 1].min() == 9.0
+
+
+# ---------------------------------------------------------------------------
+# compiled data movement against a plain numpy model of the same writes
+# ---------------------------------------------------------------------------
+class LayoutModel:
+    """A cache of the given leaves: name -> (shape with "B" and "T" standing
+    for batch and length, dtype)."""
+
+    def __init__(self, leaves):
+        self.leaves = leaves
+
+    def init_cache(self, b, cache_len):
+        sizes = {"B": b, "T": cache_len}
+        return {name: jnp.zeros(tuple(sizes.get(d, d) for d in shape), dtype)
+                for name, (shape, dtype) in self.leaves.items()}
+
+
+KV = {"k": ((2, "B", "T", 2, 3), jnp.float32), "v": ((2, "B", "T", 2, 3), jnp.float32)}
+LAYOUTS = {
+    "paged_kv": (KV, 8),
+    "kv_quant": ({"k": ((2, "B", "T", 2, 3), jnp.int8), "v": ((2, "B", "T", 2, 3), jnp.int8),
+                  "k_scale": ((2, "B", "T", 2), jnp.float32),
+                  "v_scale": ((2, "B", "T", 2), jnp.float32)}, 8),
+    "lane_state": ({**KV, "ssm": (("B", 6, 2), jnp.float32),
+                    "mem": ((2, "B", 4), jnp.float32)}, 8),
+    "replicated": ({**KV, "rope": ((16, 8), jnp.float32)}, 8),
+    "dense": ({**KV, "ssm": (("B", 6, 2), jnp.float32)}, None),
+    "split_axes": ({"k": (("B", 3, "T"), jnp.float32), "v": ((2, "T", "B"), jnp.float32)}, 8),
+}
+
+
+class NumpyPool:
+    """The pool's bytes as plain numpy, with the batch (block or lane) axis
+    first and, for paged leaves, the in-block axis second."""
+
+    def __init__(self, pool):
+        self.pool = pool
+        self.leaves = [self._canon(spec, np.asarray(a)) for spec, a in zip(pool.specs, pool._store)]
+
+    @staticmethod
+    def _canon(spec, a):
+        if spec.kind == "paged":
+            return np.moveaxis(a, (spec.batch_axis, spec.length_axis), (0, 1)).copy()
+        if spec.kind == "lane":
+            return np.moveaxis(a, spec.batch_axis, 0).copy()
+        return a.copy()
+
+    def view_leaves(self, cache):
+        return [self._canon(s, np.asarray(x)) for s, x in
+                zip(self.pool.specs, self.pool.treedef.flatten_up_to(cache))]
+
+    def table(self, lane):
+        t = list(self.pool.block_table(lane))
+        return t + [0] * (self.pool.blocks_per_lane - len(t))
+
+    def zero(self, blk):
+        for spec, a in zip(self.pool.specs, self.leaves):
+            if spec.kind == "paged":
+                a[blk] = 0
+
+    def write(self, lanes, cache, *, admit=False):
+        bs = self.pool.block_size
+        for i, (spec, v) in enumerate(zip(self.pool.specs, self.view_leaves(cache))):
+            a = self.leaves[i]
+            for row, lane in enumerate(lanes):
+                if spec.kind == "lane":
+                    a[lane] = v[row]
+                elif spec.kind == "paged":
+                    blocks = self.pool.block_table(lane) if admit else self.table(lane)
+                    for j, blk in enumerate(blocks):
+                        a[blk] = v[row, j * bs:(j + 1) * bs]
+            if spec.kind == "replicated" and not admit:
+                self.leaves[i] = v.copy()
+        if not admit:
+            self.zero(0)
+
+    def gather(self, lanes):
+        bs, out = self.pool.block_size, []
+        for spec, a in zip(self.pool.specs, self.leaves):
+            if spec.kind == "paged":
+                out.append(np.stack([np.concatenate([a[b] for b in self.table(lane)])
+                                     for lane in lanes]))
+            elif spec.kind == "lane":
+                out.append(a[list(lanes)])
+            else:
+                out.append(a)
+        return out
+
+    def check(self):
+        for spec, a, got in zip(self.pool.specs, self.leaves, self.pool._store):
+            np.testing.assert_array_equal(self._canon(spec, np.asarray(got)), a, err_msg=spec.path)
+            if spec.kind == "paged":
+                assert not np.asarray(self._canon(spec, np.asarray(got)))[0].any()  # scratch
+
+
+def _random_cache(model, rng, b, cache_len):
+    def fill(x):
+        if x.dtype == jnp.int8:
+            return jnp.asarray(rng.integers(-100, 100, size=x.shape), jnp.int8)
+        return jnp.asarray(rng.integers(1, 50, size=x.shape), x.dtype)
+
+    return jax.tree_util.tree_map(fill, model.init_cache(b, cache_len))
+
+
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
+def test_compiled_programs_match_a_numpy_model_of_the_writes(layout):
+    leaves, block_size = LAYOUTS[layout]
+    model, rng, cache_len = LayoutModel(leaves), np.random.default_rng(7), 32
+    pool = KVPool(model, lanes=3, cache_len=cache_len, block_size=block_size)
+    ref = NumpyPool(pool)
+
+    def ensure(lane, n):
+        before = set(pool.block_table(lane))
+        assert pool.ensure(lane, n)
+        for blk in set(pool.block_table(lane)) - before:
+            ref.zero(blk)
+
+    def gathered_equal(lanes):
+        for got, want in zip(ref.view_leaves(pool.gather(lanes)), ref.gather(lanes)):
+            np.testing.assert_array_equal(got, want)
+
+    ensure(0, 20)
+    ensure(2, 9)
+    cache1 = _random_cache(model, rng, 1, cache_len)
+    pool.admit(0, cache1)
+    ref.write([0], cache1, admit=True)
+    ref.check()
+    view = _random_cache(model, rng, 3, cache_len)  # lane 1 has no blocks: scratch
+    pool.scatter([0, 1, 2], view)
+    ref.write([0, 1, 2], view)
+    ref.check()
+    gathered_equal([2, 0])
+    pool.release(0)
+    ensure(1, cache_len)  # recycles lane 0's written blocks: zeroed again
+    pool.reset_lane_state(2)
+    for spec, a in zip(pool.specs, ref.leaves):
+        if spec.kind == "lane":
+            a[2] = 0
+    ref.check()
+    view = _random_cache(model, rng, 2, cache_len)
+    pool.scatter([1, 2], view)
+    ref.write([1, 2], view)
+    ref.check()
+    gathered_equal([0, 1, 2])
+    assert pool.stats()["programs_built"] > 0
+
+
+def test_pool_programs_are_built_once_per_lane_count():
+    pool = KVPool(LayoutModel(LAYOUTS["lane_state"][0]), lanes=4, cache_len=32, block_size=8)
+    for lane in range(4):
+        pool.ensure(lane, 9)
+    lanes = [0, 1, 2, 3]
+    view = pool.gather(lanes)
+    pool.scatter(lanes, view)
+    built = pool.stats()["programs_built"]
+    for _ in range(9):  # the tenth scatter at this width builds nothing
+        view = pool.gather(lanes)
+        pool.scatter(lanes, view)
+    assert pool.stats()["programs_built"] == built
+    assert pool.ensure(3, 30)  # new block ids: zeroed by the program built above
+    pool.reset_lane_state(1)
+    pool.reset_lane_state(2)
+    reset = pool.stats()["programs_built"]
+    assert reset == built + 1
+    pool.release(0)
+    assert pool.ensure(0, 32) and pool.ensure(1, 17)
+    pool.reset_lane_state(0)
+    pool.scatter(lanes, pool.gather(lanes))
+    assert pool.stats()["programs_built"] == reset
+    pool.gather([2, 3])  # a new lane count is one more program
+    assert pool.stats()["programs_built"] == reset + 1
+
+
+def test_leaves_sharing_one_buffer_get_their_own():
+    """An init_cache may return one zeros array for two leaves; the donating
+    programs need a buffer per leaf."""
+
+    class SharedZeros:
+        def init_cache(self, b, cache_len):
+            z = jnp.zeros((b, cache_len, 2), jnp.float32)
+            return {"k": z, "v": z}
+
+    pool = KVPool(SharedZeros(), lanes=2, cache_len=16, block_size=8)
+    pool.ensure(0, 16)
+    view = pool.gather([0, 1])
+    view["k"] = view["k"] + 1.0
+    pool.scatter([0, 1], view)
+    back = pool.gather([0, 1])
+    assert np.asarray(back["k"])[0].min() == 1.0
+    assert not np.asarray(back["v"]).any()

@@ -441,3 +441,47 @@ def test_long_prompts_share_one_extended_bucket():
     assert t2.request.truncated_tokens == 0
     assert t1.done and t2.done
     assert list(eng._prefill_cache) == [128]  # both hit the same 128 bucket
+
+
+# ---------------------------------------------------------------------------
+# paged pool against the dense layout, on a real model
+# ---------------------------------------------------------------------------
+def test_paged_engine_matches_dense_and_stops_building_pool_programs():
+    """Prefix-shared prompts through chunked prefill, preemption and decode
+    widths 1/2/4 give the dense ``block_size=None`` engine's tokens, and a
+    second pass over the same traffic builds no further pool program."""
+    from repro.configs import registry
+    from repro.models.model import build_model
+
+    cfg = registry.get("granite-8b").reduced()
+    model = build_model(cfg, dtype=jnp.float32, param_dtype=jnp.float32)
+    params = model.init(jax.random.PRNGKey(0))
+    rng = np.random.default_rng(11)
+    system = rng.integers(0, cfg.vocab, size=20).tolist()
+    prompts = [system + rng.integers(0, cfg.vocab, size=n).tolist() for n in (3, 9, 5, 14)]
+    prompts += [rng.integers(0, cfg.vocab, size=7).tolist()]
+    budgets = (20, 10, 18, 8, 20)
+
+    def serve(eng):
+        widths = []
+        eng.on_decode = widths.append
+        tickets = [eng.submit(prompts[0], max_new_tokens=budgets[0])]
+        while tickets[0].request.state != "active":
+            assert eng.step()  # its prefix is indexed before the others arrive
+        tickets += [eng.submit(p, max_new_tokens=n) for p, n in zip(prompts[1:], budgets[1:])]
+        eng.drain()
+        assert all(t.done for t in tickets)
+        preempted = sum(t.request.preemptions for t in tickets)
+        return [t.request.output for t in tickets], preempted, set(widths)
+
+    common = dict(max_batch=4, cache_len=64, prefill_buckets=(8, 16, 48), prefill_chunk_tokens=16)
+    dense, _, _ = serve(ServingEngine(model, params, block_size=None, **common))
+    paged = ServingEngine(model, params, block_size=4, n_blocks=20, **common)
+    first, preempted, widths = serve(paged)
+    assert first == dense
+    assert preempted >= 1 and widths == {1, 2, 4}
+    assert paged.pool.stats()["prefix_hits"] >= 1
+    built = paged.pool.stats()["programs_built"]
+    again, _, _ = serve(paged)
+    assert again == dense
+    assert paged.pool.stats()["programs_built"] == built

@@ -120,3 +120,35 @@ def test_refused_kernel_compiles_for_v5e(one_chip, family, compile_fn):
     """A kernel fix flips this to XPASS, which fails: then clear the family's
     ``tpu_refusal`` so the TPU dispatches to it."""
     assert "tpu_custom_call" in compile_fn(one_chip)
+
+
+# The chat cell's pool: 4 lanes x 128 blocks of 16 tokens over 32 layers of
+# 8 kv heads of 128, as bf16 k/v and as kv_quant's int8 k/v with float32 scales.
+_POOL_CACHES = {
+    "bf16": {"k": ((32, 8, 128), jnp.bfloat16), "v": ((32, 8, 128), jnp.bfloat16)},
+    "kv_quant": {"k": ((32, 8, 128), jnp.int8), "v": ((32, 8, 128), jnp.int8),
+                 "k_scale": ((32, 8), jnp.float32), "v_scale": ((32, 8), jnp.float32)},
+}
+
+
+@pytest.mark.parametrize("layout", sorted(_POOL_CACHES))
+def test_pool_programs_compile_for_v5e(one_chip, layout):
+    """The pool's gather and scatter at full width, as the chip compiles
+    them: the scatter updates the pool in place."""
+    from repro.serve.kvpool import KVPool
+
+    class Described:  # shapes on the described chip, (layers, batch, length, ...)
+        def init_cache(self, b, n):
+            return {name: jax.ShapeDtypeStruct((s[0], b, n) + s[1:], d, sharding=one_chip)
+                    for name, (s, d) in _POOL_CACHES[layout].items()}
+
+    pool = KVPool(Described(), lanes=4, cache_len=2048, block_size=16)
+    view = [jax.ShapeDtypeStruct(a.shape[:1] + (4, 2048) + a.shape[3:], a.dtype,
+                                 sharding=one_chip) for a in pool._leaves("paged")]
+    ids = [jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip) for n in (512, 4)]
+    gather = pool._program("kvpool_gather_w4", pool._gather_body)
+    gather.lower(pool._leaves("paged"), (), *ids).compile()
+    scatter = pool._program("kvpool_scatter_w4", pool._scatter_body, donate=(0, 1))
+    compiled = scatter.lower(pool._leaves("paged"), (), tuple(view), (), *ids).compile()
+    assert compiled.memory_analysis().alias_size_in_bytes >= sum(
+        a.size * a.dtype.itemsize for a in pool._leaves("paged"))
