@@ -6,9 +6,23 @@ import dataclasses
 
 @dataclasses.dataclass(frozen=True)
 class MoESpec:
-    n_experts: int
+    n_experts: int  # the router's width
     top_k: int
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25  # the training path's capacity buckets
+    # An expert-parallel share: this chip holds experts
+    # [first_expert, first_expert + n_held); n_held 0 holds every expert.
+    first_expert: int = 0
+    n_held: int = 0
+
+    @property
+    def held(self) -> int:
+        """How many experts this chip holds."""
+        return self.n_held or self.n_experts
+
+    def held_rows(self, tokens: int) -> int:
+        """Expected assignments of ``tokens`` that land on the held experts
+        under uniform routing (what the expert GEMMs are tuned for)."""
+        return max(1, -(-tokens * self.top_k * self.held // self.n_experts))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -44,6 +58,7 @@ class ArchConfig:
     rope_theta: float = 10_000.0
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
+    qk_norm: bool = False  # qwen3: per-head RMSNorm on q and k, before RoPE
     source: str = ""  # provenance note ([arXiv/hf; tier])
 
     # ------------------------------------------------------------------
@@ -65,7 +80,7 @@ class ArchConfig:
         if self.family == "ssm":
             attn = 6 * d * d  # rwkv time-mix r/k/v/g/o + decay
         if self.moe is not None:
-            ffn = self.moe.n_experts * 3 * d * ff + d * self.moe.n_experts
+            ffn = self.moe.held * 3 * d * ff + d * self.moe.n_experts
         else:
             ffn = 3 * d * ff
         block = attn + ffn + 2 * d
@@ -74,12 +89,14 @@ class ArchConfig:
         return L * block + enc + emb
 
     def n_active_params(self) -> int:
-        """Active params per token (MoE: only routed experts count)."""
+        """Active params per token (MoE: only routed experts count; with a
+        share, the routed experts held here, on average)."""
         if self.moe is None:
             return self.n_params()
         d, ff, L = self.d_model, self.d_ff, self.n_layers
         attn = d * self.q_dim + 2 * d * self.kv_dim + self.q_dim * d
-        ffn = self.moe.top_k * 3 * d * ff + d * self.moe.n_experts
+        moe = self.moe
+        ffn = moe.top_k * moe.held * 3 * d * ff // moe.n_experts + d * moe.n_experts
         emb = self.vocab * d * (1 if self.tie_embeddings else 2)
         return L * (attn + ffn + 2 * d) + emb
 

@@ -1,4 +1,4 @@
-"""qwen3-moe-235b-a22b [hf:Qwen/Qwen3-30B-A3B; hf] — MoE 128e top-8."""
+"""qwen3-moe-235b-a22b [hf:Qwen/Qwen3-235B-A22B; arXiv:2505.09388] — MoE 128e top-8, QK-norm."""
 from .base import ArchConfig, MoESpec
 
 CONFIG = ArchConfig(
@@ -11,5 +11,8 @@ CONFIG = ArchConfig(
     d_ff=1536,
     vocab=151936,
     moe=MoESpec(n_experts=128, top_k=8),
-    source="hf:Qwen/Qwen3-30B-A3B; hf",
+    qk_norm=True,
+    rope_theta=1_000_000.0,
+    norm_eps=1e-6,
+    source="hf:Qwen/Qwen3-235B-A22B; arXiv:2505.09388",
 )

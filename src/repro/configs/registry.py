@@ -204,6 +204,33 @@ def gemm_problems(arch: str, shape: str) -> list[tuple[int, int, int, int]]:
     return probs
 
 
+def expert_gemm_problems(arch: str, shape: str) -> list[tuple[int, int, int, int]]:
+    """The ``(rows, k, n, groups)`` grouped expert GEMMs this arch launches
+    when served at this input shape (the ``expert_gemm`` family's harvest).
+
+    Serving runs the dropless expert layer (``models/moe.py``): gate and up
+    ``(rows, d) x (d, ff)`` and down ``(rows, ff) x (ff, d)`` over the held
+    experts, ``rows`` being the assignments expected on them.  Decode runs
+    at every power-of-two lane width up to the batch; prefill runs chunks of
+    the serving ladder's widths below the sequence length.  Training uses
+    the capacity-bucketed layer, which launches none.
+    """
+    cfg = get(arch)
+    sp = SHAPES[shape]
+    if cfg.moe is None or sp.kind == "train":
+        return []
+    if sp.kind == "decode":
+        tokens = [1 << i for i in range(sp.global_batch.bit_length())]
+    else:
+        tokens = _chunk_widths(sp.seq_len, floor=32)
+    d, ff, g = cfg.d_model, cfg.d_ff, cfg.moe.held
+    out = []
+    for t in tokens:
+        rows = cfg.moe.held_rows(t)
+        out += [(rows, d, ff, g), (rows, ff, d, g)]
+    return out
+
+
 def _chunk_widths(seq_len: int, floor: int = 512) -> list[int]:
     """Chunk widths the serving ladder would use for ``seq_len`` prompts:
     geometric rungs from ``floor`` up to (exclusive) the sequence length."""

@@ -28,6 +28,7 @@ from typing import Callable, NamedTuple, Sequence
 import numpy as np
 
 from repro.kernels.attention import DEFAULT_ATTN_CONFIG, AttentionConfig, attention_config_space
+from repro.kernels.gmm import DEFAULT_GMM_CONFIG, GmmConfig, gmm_config_space
 from repro.kernels.matmul import DEFAULT_CONFIG, MatmulConfig, config_space
 from repro.kernels.ssm import DEFAULT_SSM_CONFIG, SsmConfig, ssm_config_space
 from repro.kernels.wkv import DEFAULT_WKV_CONFIG, WkvConfig, wkv_config_space
@@ -237,8 +238,33 @@ def _ssm_harvest(arch_ids):
     return harvest_ssm_problems(arch_ids)
 
 
+def _gmm_perf(problems, configs, device_name):
+    from .gmmmodel import build_gmm_matrix
+
+    return build_gmm_matrix(problems, list(configs), device_name)
+
+
+def _gmm_model(problems, configs, device_name):
+    from .gmmmodel import build_gmm_matrix
+
+    return build_gmm_matrix(problems, list(configs), device_name, texture=False)
+
+
+def _gmm_features(problems):
+    from .gmmmodel import gmm_problem_features
+
+    return gmm_problem_features(problems)
+
+
+def _gmm_harvest(arch_ids):
+    from .gmmmodel import harvest_gmm_problems
+
+    return harvest_gmm_problems(arch_ids)
+
+
 from .attnmodel import ATTN_FEATURE_NAMES  # noqa: E402
 from .dataset import FEATURE_NAMES as MATMUL_FEATURE_NAMES  # noqa: E402
+from .gmmmodel import GMM_FEATURE_NAMES  # noqa: E402
 from .recmodel import SSM_FEATURE_NAMES, WKV_FEATURE_NAMES  # noqa: E402
 
 MATMUL = register_family(
@@ -316,6 +342,24 @@ SSM_SCAN = register_family(
         tpu_refusal="NotImplementedError: Unimplemented primitive in Pallas TPU "
                     "lowering for KernelType.TC: dynamic_slice (and block_d=64 "
                     "blocks are not 128-lane aligned)",
+    )
+)
+
+EXPERT_GEMM = register_family(
+    KernelFamily(
+        name="expert_gemm",
+        config_cls=GmmConfig,
+        config_space=gmm_config_space,
+        default_config=DEFAULT_GMM_CONFIG,
+        feature_names=GMM_FEATURE_NAMES,
+        features=_gmm_features,
+        harvest=_gmm_harvest,
+        perf_matrix=_gmm_perf,
+        policy_attr="select_expert_gemm",
+        problem_arity=4,
+        reference="repro.kernels.ref.gmm_ref (jax.lax.ragged_dot)",
+        default_n_kernels=4,
+        model_matrix=_gmm_model,
     )
 )
 

@@ -42,8 +42,9 @@ from repro.core.runtime import (
 )
 
 from .attention import DEFAULT_ATTN_CONFIG, AttentionConfig, flash_attention_pallas
+from .gmm import DEFAULT_GMM_CONFIG, GmmConfig, gmm_pallas
 from .matmul import DEFAULT_CONFIG, MatmulConfig, matmul_pallas
-from .ref import flash_attention_ref
+from .ref import flash_attention_ref, gmm_ref
 from .ssm import DEFAULT_SSM_CONFIG, SsmConfig, ssm_scan_pallas
 from .wkv import DEFAULT_WKV_CONFIG, WkvConfig, wkv_pallas
 
@@ -52,6 +53,7 @@ __all__ = [
     "KernelPolicy",
     "FixedPolicy",
     "attention",
+    "expert_gemm",
     "matmul",
     "ssm_scan",
     "wkv",
@@ -382,6 +384,34 @@ def matmul(lhs: jax.Array, rhs: jax.Array, *, out_dtype=None, config: MatmulConf
         lhs2, rhs, config or DEFAULT_CONFIG, out_dtype=out_dtype, interpret=rt.interpret
     ).reshape(*lead, n)
     return _guarded_call(rt, "matmul", config, run_tuned, run_ref)
+
+
+# ---------------------------------------------------------------------------
+# grouped expert GEMM
+# ---------------------------------------------------------------------------
+@_scoped("expert_gemm")
+def expert_gemm(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array, *, rows: int | None = None,
+                out_dtype=None, config: GmmConfig | None = None) -> jax.Array:
+    """Grouped matmul with ML-guided kernel selection: ``lhs`` (m, k) rows
+    sorted by group, ``rhs`` (g, k, n), ``group_sizes`` (g,); group ``i``'s
+    rows times ``rhs[i]``, rows past the last group zero.
+
+    ``rows`` is how many rows the groups are expected to hold (a router's
+    expected assignments; default all ``m``): the group sizes are known only
+    on the device, so selection keys on the expectation,
+    ``(rows, k, n, g)``.
+    """
+    m, k = lhs.shape
+    g, _, n = rhs.shape
+    rt = current_runtime()
+    if config is None:
+        config = rt.select_config("expert_gemm", (rows or m, k, n, g))
+    run_ref = lambda: gmm_ref(lhs, rhs, group_sizes, out_dtype=out_dtype)
+    if rt.kernel_path("expert_gemm") == "xla":
+        return _guarded_call(rt, "expert_gemm", config, run_ref, run_ref)
+    run_tuned = lambda: gmm_pallas(lhs, rhs, group_sizes, config or DEFAULT_GMM_CONFIG,
+                                   out_dtype=out_dtype, interpret=rt.interpret)
+    return _guarded_call(rt, "expert_gemm", config, run_tuned, run_ref)
 
 
 # ---------------------------------------------------------------------------

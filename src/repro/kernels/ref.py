@@ -11,6 +11,14 @@ def matmul_ref(lhs: jax.Array, rhs: jax.Array, *, out_dtype=None) -> jax.Array:
     return jnp.dot(lhs, rhs, preferred_element_type=jnp.float32).astype(out_dtype)
 
 
+def gmm_ref(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array, *, out_dtype=None) -> jax.Array:
+    """Oracle for kernels/gmm.py: XLA's ragged dot, f32-accumulated; rows
+    past the last group are zero."""
+    out_dtype = out_dtype or lhs.dtype
+    return jax.lax.ragged_dot(lhs, rhs, group_sizes.astype(jnp.int32),
+                              preferred_element_type=jnp.float32).astype(out_dtype)
+
+
 def wkv_ref(r, k, v, logw, u, state=None):
     """Oracle for kernels/wkv.py: the chunked-WKV jnp reference.
 

@@ -303,6 +303,9 @@ def init_attention(rng, cfg, dtype=jnp.float32, n_layers: int | None = None) -> 
         "wv": stacked_dense_init(ks[2], n, cfg.d_model, cfg.kv_dim, dtype),
         "wo": stacked_dense_init(ks[3], n, cfg.q_dim, cfg.d_model, dtype),
     }
+    if cfg.qk_norm:
+        p["q_norm"] = jnp.ones((n, cfg.head_dim), dtype)
+        p["k_norm"] = jnp.ones((n, cfg.head_dim), dtype)
     if cfg.qkv_bias:
         p["bq"] = jnp.zeros((n, cfg.q_dim), dtype)
         p["bk"] = jnp.zeros((n, cfg.kv_dim), dtype)
@@ -350,6 +353,9 @@ def attention_layer(
     q = q.reshape(b, s, h, hd)
     k = k.reshape(b, kv_src.shape[1], kvh, hd)
     v = v.reshape(b, kv_src.shape[1], kvh, hd)
+    if cfg.qk_norm:  # per-head RMSNorm, before RoPE
+        q = rms_norm(q, p["q_norm"], cfg.norm_eps)
+        k = rms_norm(k, p["k_norm"], cfg.norm_eps)
     if use_rope and kv_input is None:
         q = rope(q, positions, cfg.rope_theta)
         k = rope(k, positions, cfg.rope_theta)

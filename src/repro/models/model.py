@@ -127,11 +127,32 @@ class TransformerLM(BaseModel):
         )
         x = x + h
         xn = L.rms_norm(x, blk["ln2"], c.norm_eps)
-        if self.is_moe:
+        if self.is_moe and cache is not None:  # serving: dropless, held experts
+            out, aux = MOE.moe_serve(blk["moe"], xn, c)  # aux: the layer's counters
+        elif self.is_moe:
             out, aux = MOE.moe_ffn(blk["moe"], xn, c)
         else:
             out, aux = L.mlp_layer(blk["ffn"], xn), 0.0
         return x + out, aux, new_cache
+
+    def _serve_layers(self, params, x, cache, **block_kw):
+        """Scan the self-attention blocks over ``x`` and their cache leaves
+        (a serving program); a MoE adds each layer's counters to the cache's
+        ``counters``, summed on the device across programs."""
+        keys = self._cache_keys
+
+        def self_body(x, inp):
+            blk, *kv = inp
+            xc, aux, kv = self._self_block(blk, x, cache=tuple(kv), **block_kw)
+            return L.shard_act(xc), ((kv, aux) if self.is_moe else kv)
+
+        x, ys = jax.lax.scan(self_body, x, (params["blocks"], *[cache[k] for k in keys]))
+        kv, counts = ys if self.is_moe else (ys, None)
+        cache = dict(cache, **dict(zip(keys, kv)))
+        if counts is not None:
+            cache["counters"] = {name: cache["counters"][name] + counts[:, i]
+                                 for i, name in enumerate(MOE.COUNTERS)}
+        return x, cache
 
     def _cross_block(self, blk: dict, x, image_embs):
         c = self.cfg
@@ -205,6 +226,8 @@ class TransformerLM(BaseModel):
             }
         else:
             cache = self._kv_cache_zeros(b, cache_len, n_self)
+        if self.is_moe:
+            cache["counters"] = {name: jnp.zeros((n_self,), jnp.int32) for name in MOE.COUNTERS}
         if self.is_vlm:
             cache["cross_k"] = jnp.zeros(
                 (self.n_groups, b, c.n_image_tokens, c.n_kv_heads, c.head_dim), self.dtype
@@ -232,10 +255,7 @@ class TransformerLM(BaseModel):
             return L.shard_act(xc), kv
 
         if not self.is_vlm:
-            x, kv = jax.lax.scan(
-                self_body, x, (params["blocks"], *[cache[k] for k in keys])
-            )
-            cache.update(zip(keys, kv))
+            x, cache = self._serve_layers(params, x, cache, positions=positions)
         else:
             g, spg = self.n_groups, self.self_per_group
             grouped = jax.tree.map(lambda a: a.reshape(g, spg, *a.shape[1:]), params["blocks"])
@@ -288,19 +308,7 @@ class TransformerLM(BaseModel):
         start = jnp.asarray(start, jnp.int32)
         positions = start + self._positions(b, s)
         x = L.shard_act(L.embed_tokens(params["emb"], tokens).astype(self.dtype))
-        keys = self._cache_keys
-
-        def self_body(x, inp):
-            blk, *kv = inp
-            xc, _, kv = self._self_block(
-                blk, x, positions, cache=tuple(kv), chunk_start=start
-            )
-            return L.shard_act(xc), kv
-
-        x, kv = jax.lax.scan(
-            self_body, x, (params["blocks"], *[cache[k] for k in keys])
-        )
-        cache = dict(cache, **dict(zip(keys, kv)))
+        x, cache = self._serve_layers(params, x, cache, positions=positions, chunk_start=start)
         x = L.rms_norm(x, params["final_norm"], c.norm_eps)
         if last_row is None:
             x_last = x[:, -1:]
@@ -324,8 +332,8 @@ class TransformerLM(BaseModel):
             return L.shard_act(xc), kv
 
         if not self.is_vlm:
-            x, kv = jax.lax.scan(self_body, x, (params["blocks"], *[cache[k] for k in keys]))
-            cache = dict(cache, **dict(zip(keys, kv)))
+            x, cache = self._serve_layers(params, x, cache, positions=pos2d,
+                                          cache_positions=positions)
         else:
             g, spg = self.n_groups, self.self_per_group
             grouped = jax.tree.map(lambda a: a.reshape(g, spg, *a.shape[1:]), params["blocks"])

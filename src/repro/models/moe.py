@@ -1,7 +1,25 @@
-"""Mixture-of-Experts FFN (token-choice top-k, capacity-bucketed, EP-sharded).
+"""Mixture-of-Experts FFN: token-choice top-k routing, two expert layers.
 
-Dispatch uses scatter/gather with capacity buckets (no dense (T, E, C)
-dispatch tensor, which would be quadratically infeasible at 1M tokens):
+Serving (prefill, chunked prefill, decode) runs :func:`moe_serve`, a dropless
+layer over the experts this chip holds (``MoESpec.first_expert`` and
+``n_held``: an expert-parallel share, every expert by default):
+
+  1. the router keeps its published form: float32 logits over every expert,
+     softmax, top-k, gates renormalised over the k;
+  2. the assignments that fall on held experts are sorted by expert (the
+     others sort last and are not computed);
+  3. the three SwiGLU products run as grouped GEMMs through
+     ``ops.expert_gemm``, one group per held expert, sized on the device;
+  4. each assignment's gate-weighted output is gathered back and summed
+     over k.  No assignment is dropped; one on an expert held elsewhere adds
+     nothing here (its chip adds it).
+
+It also returns the layer's counters: assignments on held experts and held
+experts that got at least one.
+
+Training runs :func:`moe_ffn`, which holds every expert.  Its dispatch uses
+scatter/gather with capacity buckets (no dense (T, E, C) dispatch tensor,
+which would be quadratically infeasible at 1M tokens):
 
   1. router top-k -> (token, expert) assignments;
   2. position-in-expert via a cumsum over expert one-hots;
@@ -22,30 +40,74 @@ from repro.kernels import ops
 
 from .layers import shard_moe_buf, stacked_dense_init
 
+# The serving layer's counters, per layer, in the order moe_serve returns them.
+COUNTERS = ("moe_held_assignments", "moe_held_experts_used")
+
 
 def init_moe(rng, cfg, dtype=jnp.float32, n_layers: int | None = None) -> dict:
+    """The router over every expert, and the held experts' weights."""
     n = n_layers if n_layers is not None else cfg.n_layers
-    e = cfg.moe.n_experts
+    e, held = cfg.moe.n_experts, cfg.moe.held
     ks = jax.random.split(rng, 4)
+    expert = lambda k, a, b: (jax.random.normal(k, (n, held, a, b)) * 0.02).astype(dtype)
     return {
         "router": stacked_dense_init(ks[0], n, cfg.d_model, e, dtype),
-        "w_gate": (jax.random.normal(ks[1], (n, e, cfg.d_model, cfg.d_ff)) * 0.02).astype(dtype),
-        "w_up": (jax.random.normal(ks[2], (n, e, cfg.d_model, cfg.d_ff)) * 0.02).astype(dtype),
-        "w_down": (jax.random.normal(ks[3], (n, e, cfg.d_ff, cfg.d_model)) * 0.02).astype(dtype),
+        "w_gate": expert(ks[1], cfg.d_model, cfg.d_ff),
+        "w_up": expert(ks[2], cfg.d_model, cfg.d_ff),
+        "w_down": expert(ks[3], cfg.d_ff, cfg.d_model),
     }
 
 
+def route(p: dict, xt: jax.Array, moe) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """Router over all ``moe.n_experts``: (probs (T, E), renormalised gates
+    (T, k), expert ids (T, k))."""
+    logits = ops.matmul(xt, p["router"], out_dtype=jnp.float32)
+    probs = jax.nn.softmax(logits, axis=-1)
+    gates, ids = jax.lax.top_k(probs, moe.top_k)
+    return probs, gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9), ids
+
+
+def moe_serve(p: dict, x: jax.Array, cfg) -> tuple[jax.Array, jax.Array]:
+    """x: (B, S, d) -> (out, counts (2,) int32: assignments on held experts,
+    held experts with at least one).  Params already sliced to one layer."""
+    b, s, d = x.shape
+    moe = cfg.moe
+    held, top_k = moe.held, moe.top_k
+    t = b * s
+    xt = x.reshape(t, d)
+    with jax.named_scope("ops.moe_route"):
+        _, gates, ids = route(p, xt, moe)
+        local = ids.reshape(-1) - moe.first_expert  # (T*k,), token-major
+        mine = (local >= 0) & (local < held)
+        group = jnp.where(mine, local, held)  # not held: a last group, not computed
+        order = jnp.argsort(group, stable=True)
+        sizes = jnp.bincount(group, length=held + 1)[:held].astype(jnp.int32)
+        rows = xt[order // top_k]
+    expected = moe.held_rows(t)
+    gate = ops.expert_gemm(rows, p["w_gate"], sizes, rows=expected)
+    up = ops.expert_gemm(rows, p["w_up"], sizes, rows=expected)
+    h = jax.nn.silu(gate.astype(jnp.float32)).astype(x.dtype) * up
+    y = ops.expert_gemm(h, p["w_down"], sizes, rows=expected)
+    with jax.named_scope("ops.moe_combine"):
+        slot = jnp.zeros_like(order).at[order].set(jnp.arange(order.shape[0]))
+        w = jnp.where(mine, gates.reshape(-1), 0.0)
+        out = (y[slot].astype(jnp.float32) * w[:, None]).reshape(t, top_k, d).sum(axis=1)
+    counts = jnp.stack([mine.sum(), (sizes > 0).sum()]).astype(jnp.int32)
+    return out.astype(x.dtype).reshape(b, s, d), counts
+
+
 def moe_ffn(p: dict, x: jax.Array, cfg) -> tuple[jax.Array, jax.Array]:
-    """x: (B, S, d) -> (out, aux_loss).  Params already sliced to one layer."""
+    """x: (B, S, d) -> (out, aux_loss).  Params already sliced to one layer.
+    The training layer: capacity buckets over every expert."""
     b, s, d = x.shape
     e, top_k = cfg.moe.n_experts, cfg.moe.top_k
+    if cfg.moe.held != e:
+        raise ValueError("the capacity-bucketed layer holds every expert; "
+                         f"this configuration holds {cfg.moe.held} of {e}")
     t = b * s
     xt = x.reshape(t, d)
 
-    router_logits = ops.matmul(xt, p["router"], out_dtype=jnp.float32)
-    probs = jax.nn.softmax(router_logits, axis=-1)  # (T, E)
-    gate_vals, expert_ids = jax.lax.top_k(probs, top_k)  # (T, k)
-    gate_vals = gate_vals / jnp.maximum(gate_vals.sum(-1, keepdims=True), 1e-9)
+    probs, gate_vals, expert_ids = route(p, xt, cfg.moe)  # (T, E), (T, k), (T, k)
 
     # Load-balance aux loss (Switch): E * sum_e f_e * p_e.
     me = probs.mean(0)  # mean router prob per expert

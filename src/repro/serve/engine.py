@@ -1322,6 +1322,11 @@ class ServingEngine:
             **self._pool_health(),
         )
 
+    def counters(self) -> dict:
+        """The model's program counters (``KVPool.counters``): per-layer
+        totals over every decode and chunk program this engine ran."""
+        return self.pool.counters()
+
     def _pool_health(self) -> dict:
         ps = self.pool.stats()
         return {

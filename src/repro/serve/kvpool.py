@@ -460,6 +460,15 @@ class KVPool:
             "programs_built": self._programs_built,
         }
 
+    def counters(self) -> dict:
+        """The model's program counters, to the host: the leaves of the
+        cache's ``counters`` group (replicated, summed on the device by the
+        decode and chunk programs), by name.  Read once, not per step: it
+        waits for the device."""
+        tree = jax.tree_util.tree_unflatten(self.treedef, self._store)
+        group = tree.get("counters", {}) if isinstance(tree, dict) else {}
+        return {name: np.asarray(leaf) for name, leaf in group.items()}
+
     # -- data movement ------------------------------------------------------
     # Every write or copy of pool storage is one compiled program, built on
     # first use and kept.  Programs that write the store donate the leaves

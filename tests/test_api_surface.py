@@ -96,6 +96,7 @@ OPS_ALL = [
     "KernelPolicy",
     "FixedPolicy",
     "attention",
+    "expert_gemm",
     "matmul",
     "ssm_scan",
     "wkv",

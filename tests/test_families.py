@@ -270,10 +270,12 @@ def test_v5_roundtrip_preserves_family_selections(tmp_path, tuned):
     dep.save(path)
     blob = json.loads(path.read_text())
     assert blob["version"] == 5
-    assert set(blob["families"]) == {"ssm_scan", "wkv"}
+    assert set(blob["families"]) == {"expert_gemm", "ssm_scan", "wkv"}
     back = Deployment.load(path)
     for p in [(1, 64), (2048, 64), (32768, 64)]:
         assert back.select_wkv(*p) == dep.select_wkv(*p)
+    for p in [(32, 4096, 1536, 16), (4096, 1536, 4096, 128)]:
+        assert back.select("expert_gemm", p) == dep.select("expert_gemm", p)
     for p in [(2048, 1600), (32768, 1600)]:
         assert back.select_ssm(*p) == dep.select_ssm(*p)
     assert back.meta["family_distributions"] == dep.meta["family_distributions"]
@@ -285,7 +287,7 @@ def test_unknown_family_ignored_forward_compat(tuned):
     blob["families"]["fancy_conv"] = {"configs": [{"tile": 9}], "tree": None}
     back = Deployment.from_blob(blob)
     assert "fancy_conv" not in back.families
-    assert set(back.families) == {"ssm_scan", "wkv"}  # known families intact
+    assert set(back.families) == {"expert_gemm", "ssm_scan", "wkv"}  # known families intact
 
 
 def test_family_tree_labels_validated(tuned):
@@ -379,8 +381,8 @@ def test_bundle_to_python_family_routing(tuned):
     bundle = DeploymentBundle({"tpu_v5e": tuned.deployment})
     ns = {}
     exec(bundle_to_python(bundle), ns)  # noqa: S102 — generated launcher code
-    assert set(ns["FAMILY_SELECTORS"]) == {"matmul", "attention", "ssm_scan", "wkv"}
-    for fname in ("attention", "wkv", "ssm_scan"):
+    assert set(ns["FAMILY_SELECTORS"]) == {"matmul", "attention", "expert_gemm", "ssm_scan", "wkv"}
+    for fname in ("attention", "expert_gemm", "wkv", "ssm_scan"):
         fam = get_family(fname)
         _cfgs, tree = tuned.deployment.family_tuning(fname)
         probs = fam.harvest(None)[:4]

@@ -16,6 +16,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core.families import get_family
 from repro.kernels.attention import DEFAULT_ATTN_CONFIG, flash_attention_pallas
+from repro.kernels.gmm import DEFAULT_GMM_CONFIG, GmmConfig, gmm_config_space, gmm_pallas
 from repro.kernels.matmul import DEFAULT_CONFIG, config_space, matmul_pallas
 from repro.kernels.ssm import DEFAULT_SSM_CONFIG, ssm_scan_pallas
 from repro.kernels.wkv import DEFAULT_WKV_CONFIG, wkv_pallas
@@ -85,6 +86,25 @@ def test_flash_attention_compiles_for_v5e(one_chip):
         ((512, 128), jnp.bfloat16), ((1024, 128), jnp.bfloat16), ((1024, 128), jnp.bfloat16),
     )
     assert "tpu_custom_call" in text
+
+
+# qwen3-moe-235b-a22b's expert GEMMs (k, n) over the 16 experts one chip of
+# eight holds; rows: a 32-lane decode's and a 512-token chunk's assignments.
+_QWEN3_EXPERT_GEMMS = {"gate_up": (4096, 1536), "down": (1536, 4096)}
+_GMM_CASES = [DEFAULT_GMM_CONFIG, GmmConfig(16, 128, 128),
+              max(gmm_config_space(), key=lambda c: c.vmem_bytes())]
+
+
+@pytest.mark.parametrize("m", [256, 4096], ids=["decode", "prefill_chunk"])
+@pytest.mark.parametrize("gemm", sorted(_QWEN3_EXPERT_GEMMS))
+@pytest.mark.parametrize("cfg", _GMM_CASES, ids=lambda c: c.name())
+def test_grouped_expert_gemm_compiles_for_v5e(one_chip, cfg, gemm, m):
+    k, n = _QWEN3_EXPERT_GEMMS[gemm]
+    text = _compiled_text(
+        lambda a, b, g: gmm_pallas(a, b, g, cfg), one_chip,
+        ((m, k), jnp.bfloat16), ((16, k, n), jnp.bfloat16), ((16,), jnp.int32),
+    )
+    assert "tpu_custom_call" in text and cfg.name() in text
 
 
 def _wkv(one_chip):
