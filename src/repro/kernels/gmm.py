@@ -14,8 +14,10 @@ group metadata this kernel uses):
   * the grid is ``(n tiles, visited m tiles, k tiles)``; the number of
     visited m tiles is computed on the device from the group sizes, so an
     empty group and the rows no group holds cost no grid step;
-  * scalar-prefetched group offsets, group ids and m tile ids steer each
-    step's blocks: the rhs block comes from the visit's group;
+  * scalar-prefetched group offsets, group ids, m tile ids and a layer
+    index steer each step's blocks: the rhs block comes from the visit's
+    group of that layer, read in place from a layer stack ``(L, g, k, n)``,
+    so a program that scans the layers copies no layer's weights out;
   * an f32 VMEM accumulator; the k remainder of the last k tile is masked.
 
 Config space: ``GmmConfig(tm, tk, tn)``, each a separate compiled kernel
@@ -86,7 +88,7 @@ def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m
 
 
-def _gmm_kernel(offsets, group_ids, m_tile_ids, lhs_ref, rhs_ref, out_ref, acc_ref, *,
+def _gmm_kernel(offsets, group_ids, m_tile_ids, _layer, lhs_ref, rhs_ref, out_ref, acc_ref, *,
                 tiles_k: int, k_rem: int, out_dtype):
     t, kk = pl.program_id(1), pl.program_id(2)
     tm, tn = acc_ref.shape
@@ -115,13 +117,22 @@ def _gmm_kernel(offsets, group_ids, m_tile_ids, lhs_ref, rhs_ref, out_ref, acc_r
 
 
 def gmm_pallas(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
-               config: GmmConfig = DEFAULT_GMM_CONFIG, *, out_dtype=None,
+               config: GmmConfig = DEFAULT_GMM_CONFIG, *, layer=None, out_dtype=None,
                interpret: bool = False) -> jax.Array:
     """``lhs`` (m, k) rows sorted by group, ``rhs`` (g, k, n), ``group_sizes``
     (g,) int32 with a sum of at most m -> (m, n); rows past the last group
-    are zero."""
+    are zero.
+
+    With ``layer`` (an int32 scalar, may be traced), ``rhs`` is a layer
+    stack (L, g, k, n) and the groups multiply ``rhs[layer]``, which the
+    kernel reads in place."""
+    if rhs.ndim == 3 and layer is None:
+        rhs, layer = rhs[None], 0
+    elif rhs.ndim != 4 or layer is None:
+        raise ValueError(f"gmm: rhs {rhs.shape} with layer {layer}: "
+                         "a (g, k, n) rhs takes no layer, an (L, g, k, n) stack needs one")
     m, k = lhs.shape
-    g, k2, n = rhs.shape
+    _, g, k2, n = rhs.shape
     if k != k2 or group_sizes.shape != (g,):
         raise ValueError(f"gmm: {lhs.shape} @ {rhs.shape} with group sizes {group_sizes.shape}")
     out_dtype = out_dtype or lhs.dtype
@@ -138,19 +149,21 @@ def gmm_pallas(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
     (offsets, group_ids, m_tile_ids), visits = make_group_metadata(
         group_sizes=sizes, m=mp, tm=tm, start_group=jnp.int32(0), num_nonzero_groups=g,
         visit_empty_groups=False)
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
     tiles_k = pl.cdiv(k, tk)
     kernel = functools.partial(_gmm_kernel, tiles_k=tiles_k, k_rem=k % tk, out_dtype=out_dtype)
     out = pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((mp, n), out_dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=4,
             grid=(pl.cdiv(n, tn), visits, tiles_k),
             in_specs=[
-                pl.BlockSpec((tm, tk), lambda j, t, kk, off, gid, mt: (mt[t], kk)),
-                pl.BlockSpec((None, tk, tn), lambda j, t, kk, off, gid, mt: (gid[t], kk, j)),
+                pl.BlockSpec((tm, tk), lambda j, t, kk, off, gid, mt, lyr: (mt[t], kk)),
+                pl.BlockSpec((None, None, tk, tn),
+                             lambda j, t, kk, off, gid, mt, lyr: (lyr[0], gid[t], kk, j)),
             ],
-            out_specs=pl.BlockSpec((tm, tn), lambda j, t, kk, off, gid, mt: (mt[t], j)),
+            out_specs=pl.BlockSpec((tm, tn), lambda j, t, kk, off, gid, mt, lyr: (mt[t], j)),
             scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)],
         ),
         interpret=interpret,
@@ -159,6 +172,6 @@ def gmm_pallas(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
             vmem_limit_bytes=VMEM_BYTES,
         ),
-    )(offsets, group_ids, m_tile_ids, lhs, rhs)
+    )(offsets, group_ids, m_tile_ids, layer, lhs, rhs)
     rows = jax.lax.broadcasted_iota(jnp.int32, (m, 1), 0)
     return jnp.where(rows < held, out[:m], 0).astype(out_dtype)

@@ -390,11 +390,15 @@ def matmul(lhs: jax.Array, rhs: jax.Array, *, out_dtype=None, config: MatmulConf
 # grouped expert GEMM
 # ---------------------------------------------------------------------------
 @_scoped("expert_gemm")
-def expert_gemm(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array, *, rows: int | None = None,
-                out_dtype=None, config: GmmConfig | None = None) -> jax.Array:
+def expert_gemm(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array, *, layer=None,
+                rows: int | None = None, out_dtype=None, config: GmmConfig | None = None) -> jax.Array:
     """Grouped matmul with ML-guided kernel selection: ``lhs`` (m, k) rows
     sorted by group, ``rhs`` (g, k, n), ``group_sizes`` (g,); group ``i``'s
     rows times ``rhs[i]``, rows past the last group zero.
+
+    With ``layer`` (an int32 scalar, may be traced), ``rhs`` is a layer stack
+    (L, g, k, n) and the groups multiply ``rhs[layer]``: the kernel reads that
+    layer in place, the reference slices it.
 
     ``rows`` is how many rows the groups are expected to hold (a router's
     expected assignments; default all ``m``): the group sizes are known only
@@ -402,15 +406,16 @@ def expert_gemm(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array, *, rows:
     ``(rows, k, n, g)``.
     """
     m, k = lhs.shape
-    g, _, n = rhs.shape
+    g, _, n = rhs.shape[-3:]
     rt = current_runtime()
     if config is None:
         config = rt.select_config("expert_gemm", (rows or m, k, n, g))
-    run_ref = lambda: gmm_ref(lhs, rhs, group_sizes, out_dtype=out_dtype)
+    run_ref = lambda: gmm_ref(lhs, rhs if layer is None else rhs[layer], group_sizes,
+                              out_dtype=out_dtype)
     if rt.kernel_path("expert_gemm") == "xla":
         return _guarded_call(rt, "expert_gemm", config, run_ref, run_ref)
     run_tuned = lambda: gmm_pallas(lhs, rhs, group_sizes, config or DEFAULT_GMM_CONFIG,
-                                   out_dtype=out_dtype, interpret=rt.interpret)
+                                   layer=layer, out_dtype=out_dtype, interpret=rt.interpret)
     return _guarded_call(rt, "expert_gemm", config, run_tuned, run_ref)
 
 

@@ -113,7 +113,8 @@ class TransformerLM(BaseModel):
         return params
 
     # -- one transformer block (self-attn + ffn) ---------------------------
-    def _self_block(self, blk: dict, x, positions, *, cache=None, cache_positions=None, window=0, chunk_start=None):
+    def _self_block(self, blk: dict, x, positions, *, cache=None, cache_positions=None, window=0, chunk_start=None,
+                    layer=None):
         c = self.cfg
         h, new_cache = L.attention_layer(
             blk["attn"],
@@ -128,7 +129,7 @@ class TransformerLM(BaseModel):
         x = x + h
         xn = L.rms_norm(x, blk["ln2"], c.norm_eps)
         if self.is_moe and cache is not None:  # serving: dropless, held experts
-            out, aux = MOE.moe_serve(blk["moe"], xn, c)  # aux: the layer's counters
+            out, aux = MOE.moe_serve(blk["moe"], xn, c, layer)  # aux: the layer's counters
         elif self.is_moe:
             out, aux = MOE.moe_ffn(blk["moe"], xn, c)
         else:
@@ -138,15 +139,28 @@ class TransformerLM(BaseModel):
     def _serve_layers(self, params, x, cache, **block_kw):
         """Scan the self-attention blocks over ``x`` and their cache leaves
         (a serving program); a MoE adds each layer's counters to the cache's
-        ``counters``, summed on the device across programs."""
+        ``counters``, summed on the device across programs.
+
+        A MoE's expert stacks stay out of the scanned inputs: the scan carries
+        the layer index, and the expert GEMMs read that layer of each whole
+        stack in place, so no program copies a layer's experts out."""
         keys = self._cache_keys
+        blocks, stacks, layers = params["blocks"], {}, ()
+        if self.is_moe:
+            stacks = {n: blocks["moe"][n] for n in MOE.EXPERTS}
+            blocks = dict(blocks, moe={"router": blocks["moe"]["router"]})
+            layers = (jnp.arange(blocks["ln1"].shape[0]),)
 
         def self_body(x, inp):
             blk, *kv = inp
-            xc, aux, kv = self._self_block(blk, x, cache=tuple(kv), **block_kw)
+            layer = None
+            if self.is_moe:
+                layer, *kv = kv
+                blk = dict(blk, moe=dict(blk["moe"], **stacks))
+            xc, aux, kv = self._self_block(blk, x, cache=tuple(kv), layer=layer, **block_kw)
             return L.shard_act(xc), ((kv, aux) if self.is_moe else kv)
 
-        x, ys = jax.lax.scan(self_body, x, (params["blocks"], *[cache[k] for k in keys]))
+        x, ys = jax.lax.scan(self_body, x, (blocks, *layers, *[cache[k] for k in keys]))
         kv, counts = ys if self.is_moe else (ys, None)
         cache = dict(cache, **dict(zip(keys, kv)))
         if counts is not None:

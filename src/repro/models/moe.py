@@ -10,6 +10,8 @@ layer over the experts this chip holds (``MoESpec.first_expert`` and
      others sort last and are not computed);
   3. the three SwiGLU products run as grouped GEMMs through
      ``ops.expert_gemm``, one group per held expert, sized on the device;
+     given the layer index, the GEMMs read the layer's experts in place from
+     the whole layer stacks, so the serving scan slices no expert weights;
   4. each assignment's gate-weighted output is gathered back and summed
      over k.  No assignment is dropped; one on an expert held elsewhere adds
      nothing here (its chip adds it).
@@ -42,6 +44,8 @@ from .layers import shard_moe_buf, stacked_dense_init
 
 # The serving layer's counters, per layer, in the order moe_serve returns them.
 COUNTERS = ("moe_held_assignments", "moe_held_experts_used")
+# The held experts' weights: (n_layers, held, k, n) stacks.
+EXPERTS = ("w_gate", "w_up", "w_down")
 
 
 def init_moe(rng, cfg, dtype=jnp.float32, n_layers: int | None = None) -> dict:
@@ -67,9 +71,11 @@ def route(p: dict, xt: jax.Array, moe) -> tuple[jax.Array, jax.Array, jax.Array]
     return probs, gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9), ids
 
 
-def moe_serve(p: dict, x: jax.Array, cfg) -> tuple[jax.Array, jax.Array]:
+def moe_serve(p: dict, x: jax.Array, cfg, layer=None) -> tuple[jax.Array, jax.Array]:
     """x: (B, S, d) -> (out, counts (2,) int32: assignments on held experts,
-    held experts with at least one).  Params already sliced to one layer."""
+    held experts with at least one).  The router is one layer's; the expert
+    weights are one layer's (held, k, n), or with ``layer`` (an int32 scalar,
+    may be traced) the stacks (n_layers, held, k, n), read at that layer."""
     b, s, d = x.shape
     moe = cfg.moe
     held, top_k = moe.held, moe.top_k
@@ -84,10 +90,10 @@ def moe_serve(p: dict, x: jax.Array, cfg) -> tuple[jax.Array, jax.Array]:
         sizes = jnp.bincount(group, length=held + 1)[:held].astype(jnp.int32)
         rows = xt[order // top_k]
     expected = moe.held_rows(t)
-    gate = ops.expert_gemm(rows, p["w_gate"], sizes, rows=expected)
-    up = ops.expert_gemm(rows, p["w_up"], sizes, rows=expected)
+    gate = ops.expert_gemm(rows, p["w_gate"], sizes, layer=layer, rows=expected)
+    up = ops.expert_gemm(rows, p["w_up"], sizes, layer=layer, rows=expected)
     h = jax.nn.silu(gate.astype(jnp.float32)).astype(x.dtype) * up
-    y = ops.expert_gemm(h, p["w_down"], sizes, rows=expected)
+    y = ops.expert_gemm(h, p["w_down"], sizes, layer=layer, rows=expected)
     with jax.named_scope("ops.moe_combine"):
         slot = jnp.zeros_like(order).at[order].set(jnp.arange(order.shape[0]))
         w = jnp.where(mine, gates.reshape(-1), 0.0)

@@ -63,3 +63,52 @@ def test_family_tunes_and_selects_by_rows_per_expert():
     perf = fam.perf_matrix(probs, fam.config_space(), "tpu_v5e")
     assert perf.shape == (len(probs), len(fam.config_space())) and np.all(perf >= 0)
     assert len(set(perf.argmax(1).tolist())) >= 3  # no one config wins everywhere
+
+
+# (m, k, n, group sizes, config) of a layer stack: an empty group, rows no
+# group holds, a k that the tiles do not divide (200 over tiles of 128), and
+# qwen3's expert width under a k tile wider than it (the tile shrinks to k).
+_STACK_CASES = [
+    (24, 200, 256, [5, 0, 11], GmmConfig(16, 128, 128)),
+    (40, 1536, 128, [0, 9, 7, 20], GmmConfig(16, 2048, 128)),
+]
+
+
+@pytest.mark.parametrize("path", ["gmm_pallas", "ops_xla", "ops_pallas"])
+@pytest.mark.parametrize("case", _STACK_CASES, ids=lambda c: f"k{c[1]}_{c[4].name()}")
+def test_a_layer_stack_is_read_at_the_scanned_layer(case, path):
+    """Three layers' experts in one stack; the layer index is traced, as the
+    serving scan carries it; each layer's product is that layer's."""
+    m, k, n, sizes, cfg = case
+    rng = np.random.default_rng(k)
+    lhs = jnp.asarray(rng.normal(size=(m, k)), jnp.float32)
+    stack = jnp.asarray(rng.normal(size=(3, len(sizes), k, n)), jnp.float32)
+    gs = jnp.asarray(sizes, jnp.int32)
+    rt = KernelRuntime()
+    rt.set_pallas_enabled(path == "ops_pallas", interpret=True)
+    if path == "gmm_pallas":
+        gemm = lambda layer: gmm_pallas(lhs, stack, gs, cfg, layer=layer, interpret=True)
+    else:
+        gemm = lambda layer: ops.expert_gemm(lhs, stack, gs, layer=layer, config=cfg)
+    with rt.activate():
+        _, got = jax.lax.scan(lambda c, layer: (c, gemm(layer)), 0, jnp.arange(3))
+    for layer in range(3):
+        want = np.asarray(gmm_ref(lhs, stack[layer], gs))
+        np.testing.assert_allclose(np.asarray(got[layer]), want, rtol=1e-4, atol=1e-3)
+    assert np.all(np.asarray(got)[:, sum(sizes):] == 0)  # rows no group holds
+
+
+def test_one_layer_is_a_stack_of_one():
+    rng = np.random.default_rng(3)
+    lhs = jnp.asarray(rng.normal(size=(40, 256)), jnp.float32)
+    rhs = jnp.asarray(rng.normal(size=(4, 256, 384)), jnp.float32)
+    gs = jnp.asarray([3, 0, 10, 5], jnp.int32)
+    cfg = GmmConfig(16, 128, 128)
+    one = np.asarray(gmm_pallas(lhs, rhs, gs, cfg, interpret=True))
+    np.testing.assert_array_equal(
+        one, np.asarray(gmm_pallas(lhs, rhs[None], gs, cfg, layer=0, interpret=True)))
+    np.testing.assert_allclose(one, np.asarray(gmm_ref(lhs, rhs, gs)), rtol=1e-4, atol=1e-3)
+    with pytest.raises(ValueError, match="layer"):
+        gmm_pallas(lhs, rhs, gs, cfg, layer=0, interpret=True)
+    with pytest.raises(ValueError, match="layer"):
+        gmm_pallas(lhs, rhs[None], gs, cfg, interpret=True)

@@ -5,8 +5,9 @@ float32 weights, against the plain reference the benchmark judges the chip
 runs with (``chipbench/references/moe_gqa.py``): the serving programs
 (monolithic prefill, chunks into the paged pool, paged decode) agree with
 its full forward pass; the shares of every chip add up to the whole layer;
-a router that sends every token to the same experts drops none; two planted
-faults fail the comparison; the counters equal a host count of the routing.
+a router that sends every token to the same experts drops none; planted
+faults (capacity buckets, experts not held, another layer's experts) fail
+the comparison; the counters equal a host count of the routing.
 """
 import dataclasses
 import importlib.util
@@ -125,13 +126,32 @@ def test_paged_serving_agrees_with_the_reference(first):
     assert _gap(cfg, params) < TOL
 
 
-def test_monolithic_prefill_agrees_with_the_reference():
-    cfg = _cfg()
-    model, params = _params(cfg)
+def _prefill_gap(cfg, params):
+    """Largest logit difference of monolithic prefill's last row against the
+    reference."""
+    model = build_model(cfg, dtype=jnp.float32, param_dtype=jnp.float32)
     prompt = np.asarray(jax.random.randint(jax.random.key(3), (1, 23), 0, cfg.vocab))
     logits, _ = jax.jit(model.prefill, static_argnums=2)(params, {"tokens": prompt}, 32)
     want = _ref_logits(params, cfg, prompt[0])[-1]
-    assert np.abs(np.asarray(logits[0, -1, : cfg.vocab]) - want).max() < TOL
+    return float(np.abs(np.asarray(logits[0, -1, : cfg.vocab]) - want).max())
+
+
+def test_monolithic_prefill_agrees_with_the_reference():
+    cfg = _cfg()
+    _, params = _params(cfg)
+    assert _prefill_gap(cfg, params) < TOL
+
+
+@pytest.mark.parametrize("program", [_gap, _prefill_gap], ids=["paged", "prefill"])
+def test_planted_wrong_layer_of_the_expert_stacks_fails_the_comparison(monkeypatch, program):
+    """The programs pass the whole expert stacks and the scan's layer index;
+    a layer that reads another layer's experts (of the two) fails."""
+    cfg = _cfg()
+    assert cfg.n_layers == 2
+    _, params = _params(cfg)
+    serve = MOE.moe_serve
+    monkeypatch.setattr(MOE, "moe_serve", lambda p, x, cfg, layer: serve(p, x, cfg, 1 - layer))
+    assert program(cfg, params) > 10 * TOL
 
 
 def test_the_shares_add_up_to_the_whole_layer():
@@ -190,8 +210,8 @@ def test_planted_capacity_layer_fails_the_comparison(monkeypatch):
     _, params = _params(cfg)
     assert _gap(cfg, params) < TOL
 
-    def capacity(p, x, cfg):
-        out, _ = MOE.moe_ffn(p, x, cfg)
+    def capacity(p, x, cfg, layer):
+        out, _ = MOE.moe_ffn(dict(p, **{n: p[n][layer] for n in MOE.EXPERTS}), x, cfg)
         return out, jnp.zeros(2, jnp.int32)
 
     monkeypatch.setattr(MOE, "moe_serve", capacity)

@@ -7,19 +7,25 @@ only, at phi4-mini-3.8b's published widths.  The topology is described
 inside a fixture, never at import, so every test worker collects the same
 tests and only the worker given this file loads the TPU library.
 """
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs import registry
+from repro.configs.base import MoESpec
 from repro.core.families import get_family
+from repro.core.runtime import KernelRuntime
 from repro.kernels.attention import DEFAULT_ATTN_CONFIG, flash_attention_pallas
 from repro.kernels.gmm import DEFAULT_GMM_CONFIG, GmmConfig, gmm_config_space, gmm_pallas
 from repro.kernels.matmul import DEFAULT_CONFIG, config_space, matmul_pallas
 from repro.kernels.ssm import DEFAULT_SSM_CONFIG, ssm_scan_pallas
 from repro.kernels.wkv import DEFAULT_WKV_CONFIG, wkv_pallas
+from repro.models import moe as MOE
 
 
 @pytest.fixture(scope="module")
@@ -105,6 +111,43 @@ def test_grouped_expert_gemm_compiles_for_v5e(one_chip, cfg, gemm, m):
         ((m, k), jnp.bfloat16), ((16, k, n), jnp.bfloat16), ((16,), jnp.int32),
     )
     assert "tpu_custom_call" in text and cfg.name() in text
+
+
+def test_expert_layer_reads_the_expert_stacks_in_place_on_v5e(one_chip):
+    """Two layers of the serving expert layer at qwen3's widths (d 4096,
+    expert width 1536, 16 held, top-8 of 128), 32 tokens, scanned as the
+    serving programs scan them: the grouped GEMMs take each whole layer stack
+    and the scan's layer index, and no op copies a layer's experts out."""
+    cfg = dataclasses.replace(registry.get("qwen3-moe-235b-a22b"), n_layers=2,
+                              moe=MoESpec(128, 8, first_expert=0, n_held=16))
+    d, f, held, bf16 = cfg.d_model, cfg.d_ff, cfg.moe.held, jnp.bfloat16
+    assert (d, f, cfg.moe.top_k) == (4096, 1536, 8)
+
+    def two_layers(x, router, w_gate, w_up, w_down):
+        stacks = {"w_gate": w_gate, "w_up": w_up, "w_down": w_down}
+
+        def body(x, inp):
+            r, layer = inp
+            out, _ = MOE.moe_serve(dict(stacks, router=r), x, cfg, layer)
+            return x + out, None
+
+        return jax.lax.scan(body, x, (router, jnp.arange(2)))[0]
+
+    rt = KernelRuntime()
+    rt.set_pallas_enabled(True)
+    with rt.activate():
+        text = _compiled_text(
+            two_layers, one_chip, ((32, 1, d), bf16), ((2, d, 128), bf16),
+            ((2, held, d, f), bf16), ((2, held, d, f), bf16), ((2, held, f, d), bf16))
+    gemms = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line and "%gmm_" in line]
+    assert len(gemms) == 3
+    for line in gemms:  # operands are printed with their shapes
+        assert re.search(r"custom-call\(.*bf16\[2,16,(4096,1536|1536,4096)\]", line)
+    # expert bytes sliced per program: 0 (no op yields one layer's experts,
+    # and none copies a whole stack)
+    assert not re.search(r"= bf16\[16,(4096,1536|1536,4096)\]", text)
+    assert not re.search(r"= bf16\[2,16,(4096,1536|1536,4096)\]\S* copy", text)
 
 
 def _wkv(one_chip):
